@@ -2,7 +2,8 @@
 
 The simulator proves scheduling at cluster scale; this module proves the
 *plumbing* end-to-end — every agent invocation is a real JAX program over
-real arrays, using the model zoo's reduced configs on CPU:
+real arrays, using the model zoo's reduced configs on whatever device JAX
+finds (the Pallas kernels on a TPU, their jnp twins elsewhere):
 
   frame_extract   strided frame sampling (jnp slicing/pooling)
   speech_to_text  seamless-m4t (reduced) enc-dec generate over audio features

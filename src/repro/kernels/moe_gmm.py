@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params as _compiler_params
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
     kd = pl.program_id(3)
@@ -79,7 +77,7 @@ def gmm_pallas(x, w, *, block_c=128, block_f=128, block_d=512,
                                lambda e, ic, jf, kd: (e, ic, jf)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, fp), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

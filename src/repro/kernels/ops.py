@@ -1,17 +1,12 @@
 """Jit'd kernel wrappers with backend dispatch.
 
-On TPU the Pallas kernels run natively; on CPU (tests, dry-run lowering) we
-execute the chunked pure-jnp twins from ``ref.py`` — identical math, scan-based
-so the lowered HLO keeps O(block) intermediates (this is what makes the
-dry-run roofline's memory term honest; see EXPERIMENTS.md §Roofline).
-
-Set ``REPRO_FORCE_REF=1`` to force the reference path everywhere, or
-``REPRO_PALLAS_INTERPRET=1`` to run Pallas kernels in interpret mode (slow;
-kernel tests do this explicitly with small shapes).
+On the TPU backend the Pallas kernels always run compiled; on every other
+backend (CPU tests, dry-run lowering) we execute the chunked pure-jnp twins
+from ``ref.py`` — identical math, scan-based so the lowered HLO keeps
+O(block) intermediates. Kernel tests call the Pallas kernels themselves with
+``interpret=True``.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,14 +18,7 @@ from .ssd_scan import ssd_scan_pallas
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_REF"):
-        return False
-    return jax.default_backend() == "tpu" or bool(
-        os.environ.get("REPRO_PALLAS_INTERPRET"))
-
-
-def _interpret() -> bool:
-    return bool(os.environ.get("REPRO_PALLAS_INTERPRET"))
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
@@ -49,8 +37,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
             return flash_attention_pallas(
                 q, k, v, causal=causal, window=window, softcap=logit_softcap,
                 scale=scale, q_offset=q_offset,
-                kv_valid=kv_len if kv_len is not None else None,
-                interpret=_interpret())
+                kv_valid=kv_len)
         kv = kv_len
         if isinstance(kv, int):
             kv = jnp.full((q.shape[0],), kv, jnp.int32)
@@ -65,10 +52,10 @@ def decode_attention(q, k, v, *, window=0, logit_softcap=0.0, scale=None,
 
     q_offset/kv_len may be traced arrays (dynamic decode position).
 
-    bf16_kv (perf, EXPERIMENTS.md §Perf A1): contract K/V in their stored
-    dtype with fp32 accumulation (``preferred_element_type``) instead of
-    upcasting — an ``astype(f32)`` here makes XLA hoist a full-cache fp32
-    copy out of the decode loop (2x HBM for the cache + 2x read traffic).
+    bf16_kv: contract K/V in their stored dtype with fp32 accumulation
+    (``preferred_element_type``) instead of upcasting — an ``astype(f32)``
+    here makes XLA hoist a full-cache fp32 copy out of the decode loop (2x
+    HBM for the cache + 2x read traffic).
     The softmax stays fp32; P is fed to the PV product in bf16 (exactly the
     MXU mixed-precision scheme the Pallas flash kernel uses).
     """
@@ -110,8 +97,7 @@ def decode_attention(q, k, v, *, window=0, logit_softcap=0.0, scale=None,
 def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk=128):
     with jax.named_scope("pk_ssd_scan"):
         if _use_pallas():
-            return ssd_scan_pallas(x, dt, a_log, b, c, d_skip, chunk=chunk,
-                                   interpret=_interpret())
+            return ssd_scan_pallas(x, dt, a_log, b, c, d_skip, chunk=chunk)
         return ref.ssd_chunked(x, dt, a_log, b, c, d_skip, chunk_size=chunk)
 
 
@@ -123,5 +109,5 @@ def gmm(x, w):
     """Grouped per-expert matmul: (E, C, d) @ (E, d, f) -> (E, C, f)."""
     with jax.named_scope("pk_gmm"):
         if _use_pallas():
-            return gmm_pallas(x, w, interpret=_interpret())
+            return gmm_pallas(x, w)
         return ref.gmm_naive(x, w)

@@ -21,11 +21,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params as _compiler_params
 
-
-def _ssd_kernel(u_ref, la_ref, b_ref, c_ref, y_ref, state_ref, s_scr, *,
-                chunk: int):
+def _ssd_kernel(u_ref, cum_col_ref, cum_row_ref, b_ref, c_ref, y_ref,
+                state_ref, s_scr, *, chunk: int):
     ic = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -33,15 +31,15 @@ def _ssd_kernel(u_ref, la_ref, b_ref, c_ref, y_ref, state_ref, s_scr, *,
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    u = u_ref[0, 0].astype(jnp.float32)        # (Q, P)
-    la = la_ref[0, 0].astype(jnp.float32)      # (Q,)
+    u = u_ref[0, 0]                            # (Q, P) fp32
+    cum_c = cum_col_ref[0, 0]                  # (Q, 1) in-chunk cumsum of dt*A
+    cum_r = cum_row_ref[0, 0, 0]               # (1, Q) the same, as a row
     b = b_ref[0, 0].astype(jnp.float32)        # (Q, N)
     c = c_ref[0, 0].astype(jnp.float32)        # (Q, N)
 
-    cum = jnp.cumsum(la)                       # (Q,)
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q, Q)
-    decay = jnp.exp(cum[:, None] - cum[None, :])
+    decay = jnp.exp(cum_c - cum_r)
     ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     w = jnp.where(si <= ti, cb * decay, 0.0)
@@ -50,14 +48,16 @@ def _ssd_kernel(u_ref, la_ref, b_ref, c_ref, y_ref, state_ref, s_scr, *,
     # carried-state contribution: y_t += exp(cum_t) * (c_t . S_prev)
     y_state = jax.lax.dot_general(c, s_scr[...], (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y = y + y_state * jnp.exp(cum)[:, None]
+    y = y + y_state * jnp.exp(cum_c)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: S = exp(tot) * S_prev + sum_s exp(tot - cum_s) u_s b_s^T
-    tot = cum[chunk - 1]
-    w_end = jnp.exp(tot - cum)                 # (Q,)
-    s_loc = jax.lax.dot_general(u * w_end[:, None], b,
-                                (((0,), (0,)), ((), ())),
+    # last element of the row, read by a masked lane reduction (a static
+    # slice at lane Q-1 leaves a layout the TPU cannot broadcast)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    tot = jnp.sum(jnp.where(last, cum_r, 0.0), axis=1, keepdims=True)  # (1, 1)
+    w_end = jnp.exp(tot - cum_c)               # (Q, 1)
+    s_loc = jax.lax.dot_general(u * w_end, b, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (P, N)
     s_scr[...] = s_scr[...] * jnp.exp(tot) + s_loc
 
@@ -84,6 +84,13 @@ def ssd_scan_pallas(x, dt, a_log, b, c, d_skip, *, chunk=128,
     A = -jnp.exp(a_log.astype(jnp.float32))
     dtf = dt.astype(jnp.float32)
     la = (dtf * A[None, None]).transpose(0, 2, 1)           # (B, H, L)
+    # In-chunk cumulative decay, handed to the kernel in two layouts (a
+    # column and a row per chunk) so every block's last two dims satisfy
+    # the TPU's (8, 128) tiling rule and the kernel needs no cumsum or
+    # vector transpose of its own.
+    cum = jnp.cumsum(la.reshape(B, H, nc, chunk), axis=-1)
+    cum_col = cum.reshape(B, H, L, 1)
+    cum_row = cum.reshape(B, H, nc, 1, chunk)
     u = (x.astype(jnp.float32) * dtf[..., None]).transpose(0, 2, 1, 3)
     bt = b.transpose(0, 2, 1, 3)                            # (B, G, L, N)
     ct = c.transpose(0, 2, 1, 3)
@@ -94,7 +101,9 @@ def ssd_scan_pallas(x, dt, a_log, b, c, d_skip, *, chunk=128,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda ib, ih, ic: (ib, ih, ic, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda ib, ih, ic: (ib, ih, ic)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda ib, ih, ic: (ib, ih, ic, 0, 0)),
             pl.BlockSpec((1, 1, chunk, N),
                          lambda ib, ih, ic, r=rep: (ib, ih // r, ic, 0)),
             pl.BlockSpec((1, 1, chunk, N),
@@ -109,10 +118,10 @@ def ssd_scan_pallas(x, dt, a_log, b, c, d_skip, *, chunk=128,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(u, la, bt, ct)
+    )(u, cum_col, cum_row, bt, ct)
 
     y = y.transpose(0, 2, 1, 3)
     y = y + x.astype(jnp.float32).astype(y.dtype) * \

@@ -16,7 +16,7 @@ coherent without hardware:
 
 Per cell it records a JSON blob (results/dryrun/) with per-device memory,
 HLO FLOPs/bytes, and per-collective byte counts parsed from the optimized
-HLO — the inputs to EXPERIMENTS.md §Roofline.
+HLO — the inputs to the roofline estimate in ``launch/hlo_cost.py``.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch deepseek-7b \
@@ -181,9 +181,7 @@ def _lower_cell(cfg, cell, mesh, *, rules=None, opts_over=None,
     model = build_model(cfg)
     B, S = cell.global_batch, cell.seq_len
     opts_over = opts_over or {}
-    # jax.set_mesh arrived in 0.6; on older jax the Mesh is its own context
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             opts = train_rt.TrainOptions(**{"remat_policy": "full",
                                             "microbatches": 1,
@@ -431,7 +429,7 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--serving-rules", action="store_true",
-                    help="optimized serve-time sharding (EXPERIMENTS §Perf)")
+                    help="optimized serve-time sharding (SERVING_RULES)")
     ap.add_argument("--out", default=RESULTS_DIR)
     args = ap.parse_args()
 

@@ -9,21 +9,14 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(axes: tuple[str, ...]) -> dict:
-    """``axis_types`` exists from jax 0.5; older releases default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * len(axes)}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e pod mesh: 16x16 = 256 chips/pod; 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(axes))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (smoke tests, elastic remesh plans)."""
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -1,8 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Continuous-batched serving of queued generation requests against a zoo
-model (reduced configs on CPU; the same ServeSession path the Murakkab
-real-executor uses). Reports throughput and per-request latency.
+Serves queued generation requests against a zoo model in static batches
+(the last one padded), through the same ServeSession path the Murakkab
+real executor uses. Reduced configs by default; ``--no-reduced`` serves the
+published widths. Reports throughput and per-batch latency.
 
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-370m \
         --requests 16 --batch 4 --prompt-len 32 --max-new 16
@@ -19,12 +20,14 @@ import numpy as np
 from ..configs.registry import ARCH_IDS, get_config
 from ..models.model_zoo import build_model
 from ..runtime.serve import ServeOptions, ServeSession
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="mamba2-370m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -32,6 +35,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)

@@ -11,6 +11,7 @@ the single source of truth from which we derive:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, NamedTuple
 
@@ -49,7 +50,21 @@ def _fan_in(shape: tuple[int, ...], axes: tuple[str | None, ...]) -> int:
     dims = [d for d, a in zip(shape, axes) if a not in ("layers", "group")]
     if len(dims) <= 1:
         return max(dims[0] if dims else 1, 1)
-    return max(int(jnp.prod(jnp.array(dims[:-1]))), 1)
+    return max(math.prod(dims[:-1]), 1)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _normal_leaf(key, std, shape, dtype):
+    """One leaf drawn on the device in its own dtype. Under jit the fp32
+    draw, the scaling and the cast fuse, so no fp32 array the size of the
+    leaf is ever resident (a 7B model's stacked FFN leaf would need 5 GiB).
+
+    The no-op ``reduce_precision`` keeps XLA from folding ``std`` into
+    ``normal``'s own sqrt(2) factor, which would move the last bit of many
+    values away from what the unjitted draw gives for the same key."""
+    x = jax.lax.reduce_precision(jax.random.normal(key, shape, jnp.float32),
+                                 exponent_bits=8, mantissa_bits=23)
+    return (x * std).astype(dtype)
 
 
 def init_params(spec_tree, key, default_dtype=jnp.bfloat16):
@@ -64,9 +79,9 @@ def init_params(spec_tree, key, default_dtype=jnp.bfloat16):
             return jnp.ones(s.shape, dt)
         if s.init == "embed":
             std = s.scale or 1.0
-            return (jax.random.normal(k, s.shape, jnp.float32) * std).astype(dt)
-        std = s.scale or 1.0 / math.sqrt(_fan_in(s.shape, s.axes))
-        return (jax.random.normal(k, s.shape, jnp.float32) * std).astype(dt)
+        else:
+            std = s.scale or 1.0 / math.sqrt(_fan_in(s.shape, s.axes))
+        return _normal_leaf(k, std, s.shape, dt)
 
     return jax.tree.unflatten(treedef, [_init(s, k) for s, k in zip(leaves, keys)])
 

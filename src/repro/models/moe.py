@@ -26,16 +26,6 @@ from ..kernels import ops
 from .common import ParamSpec
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map (0.6+) vs jax.experimental.shard_map (older)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 @dataclass(frozen=True)
 class DistContext:
     """How apply-fns should distribute themselves (None mesh = local)."""
@@ -219,13 +209,14 @@ def apply_moe(p, x, *, cfg, dist: DistContext = LOCAL):
             ep_wd_spec = P(dist.model_axis, None, dist.data_axes)
         else:
             ep_w_spec = ep_wd_spec = P(dist.model_axis, None, None)
-        out, aux = _shard_map(
+        out, aux = jax.shard_map(
             lambda xl, rw, wg, wu, wd: _moe_ep_body(
                 xl, rw, wg, wu, wd, cfg=cfg, dist=dist),
             mesh=dist.mesh,
             in_specs=(P(dist.data_axes, None), P(None, None),
                       ep_w_spec, ep_w_spec, ep_wd_spec),
             out_specs=(P(dist.data_axes, None), P()),
+            check_vma=False,
         )(x2d, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if m.num_shared:

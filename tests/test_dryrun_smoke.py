@@ -60,9 +60,7 @@ def test_mini_dryrun_subprocess(arch):
         batch_abs = {{"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
                      "labels": jax.ShapeDtypeStruct((B, S), jnp.int32),
                      **model.extra_inputs(B, S, abstract=True)}}
-        # jax.set_mesh arrived in 0.6; older jax uses the Mesh as context
-        mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-        with mesh_ctx:
+        with jax.set_mesh(mesh):
             fn = train_rt.jit_train_step(model, opts, mesh, batch_abs)
             st_abs = train_rt.abstract_train_state(model, opts)
             lowered = fn.lower(st_abs, batch_abs)

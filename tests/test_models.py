@@ -1,5 +1,7 @@
 """Model-level correctness: KV-cache decode == full forward, RoPE/norm
 properties, MoE routing invariants."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs.registry import ARCH_IDS, get_config
-from repro.models import moe as moe_mod
+from repro.models import common, moe as moe_mod
 from repro.models.common import apply_rope, rms_norm, softcap
 from repro.models.model_zoo import build_model
 from repro.runtime import serve as serve_rt
@@ -49,6 +51,30 @@ def test_decode_equals_forward(arch):
     np.testing.assert_allclose(np.asarray(last),
                                np.asarray(logits_full[:, -1]),
                                atol=DECODE_TOL, rtol=DECODE_TOL)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_init_matches_the_eager_draw(arch):
+    """``init_params`` draws each leaf under jit (no fp32 copy of the leaf is
+    ever resident); the values stay bit for bit those of the eager fp32
+    draw, scaled, then cast, which seeded tests were written against."""
+    model = build_model(get_config(arch, reduced=True))
+    key = jax.random.PRNGKey(3)
+    specs = jax.tree.leaves(model.specs, is_leaf=common._is_spec)
+    got = jax.tree.leaves(model.init(key))
+    assert len(got) == len(specs)
+    for s, k, x in zip(specs, jax.random.split(key, len(specs)), got):
+        dt = s.dtype or common.dtype_of(model.cfg.param_dtype)
+        if s.init in ("zeros", "ones"):
+            want = (jnp.zeros if s.init == "zeros" else jnp.ones)(s.shape, dt)
+        else:
+            std = s.scale or (1.0 if s.init == "embed" else
+                              1.0 / math.sqrt(common._fan_in(s.shape, s.axes)))
+            want = (jax.random.normal(k, s.shape, jnp.float32)
+                    * std).astype(dt)
+        assert x.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 def test_rope_relative_property():

@@ -1,0 +1,105 @@
+"""Compile the device path for a described TPU v5e, with no chip attached.
+
+The TPU compiler refuses what the Pallas interpreter accepts: blocks off the
+(8, 128) tiling, too much VMEM, a program larger than HBM. These tests compile
+the three kernels at the published widths of the models that use them, and the
+full-width deepseek-7b decode step, for one chip of a ``v5e:2x2`` topology.
+Nothing runs; only the compiler is exercised.
+
+The topology is described inside a fixture (never at import): only one
+process may load the TPU library, and every pytest worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.moe_gmm import gmm_pallas
+from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.models.model_zoo import build_model
+from repro.runtime.serve import ServeOptions, abstract_cache, build_decode_step
+
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler or library lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compilation cache off: a
+    compile for a described device is written to the cache but cannot be
+    read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("seq", [128, 2048])
+def test_flash_attention_compiles_at_deepseek_7b_widths(one_chip, seq):
+    cfg = get_config("deepseek-7b")
+    qkv = _on(one_chip, (4, seq, cfg.n_heads, cfg.head_dim_))
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention_pallas(q, k, v, causal=True)
+    ).lower(qkv, qkv, qkv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gmm_compiles_at_deepseek_moe_16b_expert_widths(one_chip):
+    cfg = get_config("deepseek-moe-16b")
+    E, d, f = cfg.moe.num_experts, cfg.d_model, cfg.moe.d_ff_expert
+    compiled = jax.jit(gmm_pallas).lower(
+        _on(one_chip, (E, 128, d)), _on(one_chip, (E, d, f))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles_at_mamba2_370m_widths(one_chip):
+    cfg = get_config("mamba2-370m")
+    s = cfg.ssm
+    H = s.expand * cfg.d_model // s.head_dim
+    B, L, f32 = 2, 2 * s.chunk_size, jnp.float32
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, d: ssd_scan_pallas(x, dt, a, b, c, d,
+                                                  chunk=s.chunk_size)
+    ).lower(_on(one_chip, (B, L, H, s.head_dim)),
+            _on(one_chip, (B, L, H), f32), _on(one_chip, (H,), f32),
+            _on(one_chip, (B, L, s.n_groups, s.d_state)),
+            _on(one_chip, (B, L, s.n_groups, s.d_state)),
+            _on(one_chip, (H,), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_deepseek_7b_decode_step_fits_one_v5e(one_chip):
+    """Published widths, batch 4, a 144-token cache: what chip_smoke.py
+    serves. Parameters, cache and outputs must fit the chip's 16 GiB."""
+    model = build_model(get_config("deepseek-7b"))
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(one_chip, a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(abstract_cache(model, 4, 144))
+    compiled = jax.jit(build_decode_step(model, ServeOptions())).lower(
+        params, cache, _on(one_chip, (4, 1), jnp.int32),
+        _on(one_chip, (), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes
+    assert 12 * 2**30 < total < V5E_HBM_BYTES, total / 2**30
