@@ -13,8 +13,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-SUITES = ("fig3", "table2", "table1", "overheads", "multitenant",
-          "kernels", "roofline")
+SUITES = ("fig3", "table2", "table1", "overheads", "multitenant")
 
 
 def main() -> None:
@@ -24,12 +23,11 @@ def main() -> None:
     args = ap.parse_args()
     picked = [s.strip() for s in args.only.split(",") if s.strip()]
 
-    from . import (fig3_traces, kernels_bench, multitenant, overheads,
-                   roofline, table1_levers, table2_energy)
+    from . import (fig3_traces, multitenant, overheads, table1_levers,
+                   table2_energy)
     mods = {"fig3": fig3_traces, "table2": table2_energy,
             "table1": table1_levers, "overheads": overheads,
-            "multitenant": multitenant, "kernels": kernels_bench,
-            "roofline": roofline}
+            "multitenant": multitenant}
 
     all_rows: list[tuple[str, float, str]] = []
     failures = []

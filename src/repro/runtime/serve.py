@@ -17,6 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..models.model_zoo import Model
 from ..models.moe import DistContext, LOCAL
 from . import sharding as shd
+from . import tracing
 
 
 @dataclass(frozen=True)
@@ -140,17 +141,32 @@ class ServeSession:
                               donate_argnums=(1,))
 
     def generate(self, prompts, max_new_tokens: int = 32, extras=None):
-        """prompts: (B, S) int32 array -> (B, max_new_tokens) int32."""
+        """prompts: (B, S) int32 array -> (B, max_new_tokens) int32.
+
+        Under a profiler session each call records the spans ``serve.*``
+        of one request (``tracing``): the spans time the host's dispatch,
+        the device runs behind them."""
         B, S = prompts.shape
-        enc_len = self.model.enc_len_for(S)
-        cache = self.model.init_cache(B, S + max_new_tokens, enc_len=enc_len)
-        inputs = {"tokens": prompts, **(extras or {})}
-        last_logits, cache = self.prefill(self.params, inputs, cache)
-        tok = jnp.argmax(last_logits, -1).astype(jnp.int32)[:, None]
-        out = [tok]
-        idx = jnp.asarray(S, jnp.int32)
-        for _ in range(max_new_tokens - 1):
-            tok, _, cache = self.decode(self.params, cache, tok, idx)
-            out.append(tok)
-            idx = idx + 1
-        return jnp.concatenate(out, axis=1)
+        with tracing.span("serve.generate", batch=B, prompt_len=S,
+                          new_tokens=max_new_tokens) as call:
+            enc_len = self.model.enc_len_for(S)
+            with tracing.span("serve.init_cache"):
+                cache = self.model.init_cache(B, S + max_new_tokens,
+                                              enc_len=enc_len)
+            if call is not None:
+                call.set(cache_bytes=sum(x.nbytes
+                                         for x in jax.tree.leaves(cache)))
+            inputs = {"tokens": prompts, **(extras or {})}
+            with tracing.span("serve.prefill"):
+                last_logits, cache = self.prefill(self.params, inputs, cache)
+            with tracing.span("serve.sample"):
+                tok = jnp.argmax(last_logits, -1).astype(jnp.int32)[:, None]
+            out = [tok]
+            idx = jnp.asarray(S, jnp.int32)
+            for step in range(1, max_new_tokens):
+                with tracing.span("serve.decode", step=step):
+                    tok, _, cache = self.decode(self.params, cache, tok, idx)
+                    idx = idx + 1
+                out.append(tok)
+            with tracing.span("serve.concat"):
+                return jnp.concatenate(out, axis=1)
