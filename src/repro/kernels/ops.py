@@ -46,52 +46,55 @@ def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
                                q_offset=q_offset, kv_len=kv, block_k=block_k)
 
 
-def decode_attention(q, k, v, *, window=0, logit_softcap=0.0, scale=None,
-                     q_offset, kv_len, bf16_kv: bool = True):
-    """Single-token (Sq small) attention over a cache; plain jnp GEMV path.
+def decode_attention(q, k_cache, v_cache, k_new, v_new, *, cache_index,
+                     window=0, logit_softcap=0.0, scale=None):
+    """Attention of S new tokens over a cache they are not yet written into;
+    plain jnp GEMV path.
 
-    q_offset/kv_len may be traced arrays (dynamic decode position).
+    q: (B, S, H, D) at positions ``cache_index + i``; k_cache, v_cache:
+    (B, T, KVH, D); k_new, v_new: (B, S, KVH, D), the new tokens' own K/V in
+    the cache dtype. The new tokens see the cache's positions below
+    ``cache_index`` and each other causally, under ``window`` and
+    ``logit_softcap``: the same as attention over the cache with the new rows
+    written at ``cache_index``, without that write. cache_index may be traced.
 
-    bf16_kv: contract K/V in their stored dtype with fp32 accumulation
-    (``preferred_element_type``) instead of upcasting — an ``astype(f32)``
-    here makes XLA hoist a full-cache fp32 copy out of the decode loop (2x
-    HBM for the cache + 2x read traffic).
-    The softmax stays fp32; P is fed to the PV product in bf16 (exactly the
-    MXU mixed-precision scheme the Pallas flash kernel uses).
+    K/V are contracted in their stored dtype with fp32 accumulation
+    (``preferred_element_type``) instead of upcast: an ``astype(f32)`` here
+    makes XLA hoist a full-cache fp32 copy out of the decode loop (2x HBM for
+    the cache + 2x read traffic). One fp32 softmax spans the cache's columns
+    and the new ones; P is fed to both PV products in bf16 (exactly the MXU
+    mixed-precision scheme the Pallas flash kernel uses).
     """
-    B, Sq, H, D = q.shape
-    _, Sk, KVH, _ = k.shape
-    g = H // KVH
+    B, S, H, D = q.shape
+    T, KVH = k_cache.shape[1], k_cache.shape[2]
     scale = scale if scale is not None else D ** -0.5
-    ns = jax.named_scope("pk_decode_attention")
-    ns.__enter__()
-    if bf16_kv:
-        qf = q.reshape(B, Sq, KVH, g, D)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qf, k,
-                       preferred_element_type=jnp.float32) * scale
-    else:
-        qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, KVH, g, D)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qf, k.astype(jnp.float32))
-    if logit_softcap:
-        s = logit_softcap * jnp.tanh(s / logit_softcap)
-    q_pos = jnp.asarray(q_offset)[..., None] + jnp.arange(Sq)  # (B?,Sq)
-    q_pos = jnp.broadcast_to(q_pos, (B, Sq))
-    k_pos = jnp.arange(Sk)
-    m = k_pos[None, None, :] <= q_pos[..., None]
-    kv = jnp.broadcast_to(jnp.asarray(kv_len), (B,))
-    m &= k_pos[None, None, :] < kv[:, None, None]
-    if window:
-        m &= q_pos[..., None] - k_pos[None, None, :] < window
-    s = jnp.where(m[:, None, None], s, ref.NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    if bf16_kv:
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
-                       preferred_element_type=jnp.float32)
-    else:
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    out = o.reshape(B, Sq, H, D).astype(q.dtype)
-    ns.__exit__(None, None, None)
-    return out
+    with jax.named_scope("pk_decode_attention"):
+        qg = q.reshape(B, S, KVH, H // KVH, D)
+
+        def scores(k, mask):
+            s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                           preferred_element_type=jnp.float32) * scale
+            if logit_softcap:
+                s = logit_softcap * jnp.tanh(s / logit_softcap)
+            return jnp.where(mask, s, ref.NEG_INF)
+
+        i, t = jnp.arange(S)[:, None], jnp.arange(T)[None, :]
+        m_cache = t < cache_index
+        m_new = i.T <= i
+        if window:
+            m_cache &= cache_index + i - t < window
+            m_new &= i - i.T < window
+        s_cache, s_new = scores(k_cache, m_cache), scores(k_new, m_new)
+        top = jnp.maximum(s_cache.max(-1), s_new.max(-1))[..., None]
+        p_cache, p_new = jnp.exp(s_cache - top), jnp.exp(s_new - top)
+        total = p_cache.sum(-1, keepdims=True) + p_new.sum(-1, keepdims=True)
+
+        def pv(p, v):
+            return jnp.einsum("bhgqk,bkhd->bqhgd", (p / total).astype(v.dtype),
+                              v, preferred_element_type=jnp.float32)
+
+        o = pv(p_cache, v_cache) + pv(p_new, v_new)
+        return o.reshape(B, S, H, D).astype(q.dtype)
 
 
 def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk=128):

@@ -29,14 +29,6 @@ def attention_specs(cfg, d_model: int | None = None) -> dict:
     return spec
 
 
-def init_kv_cache(cfg, batch: int, max_len: int, n_layers: int,
-                  dtype=jnp.bfloat16, lead: tuple[int, ...] = ()):
-    """KV cache pytree: k/v of (n_layers, *lead, batch, max_len, kv_heads, hd)."""
-    hd = cfg.head_dim_
-    shape = (n_layers, *lead, batch, max_len, cfg.n_kv_heads, hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
 def _project_qkv(p, x, cfg):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -55,13 +47,19 @@ def apply_attention(p, x, *, cfg, window: int = 0, positions=None,
                     cache: dict | None = None, cache_index=None,
                     cross_kv: tuple | None = None, causal: bool = True,
                     mode: str = "train"):
-    """x: (B, S, d). Returns (out, new_cache_slice).
+    """x: (B, S, d). Returns (out, kv_rows).
 
-    - train: no cache IO, flash attention over x.
-    - prefill: flash attention over x; k/v written into ``cache`` at 0.
-    - decode: k/v written at ``cache_index``; attention over the cache.
+    - train: no cache, flash attention over x.
+    - prefill: flash attention over x.
+    - decode: attention over ``cache`` (this layer's (B, T, KVH, hd) slice,
+      read only) at positions below ``cache_index``, plus the new tokens.
     - cross-attention: cross_kv = (k, v) precomputed from encoder/vision
       states; causal is ignored (full visibility).
+
+    kv_rows: None without a cache, else this call's {"k", "v"} of (B, S,
+    KVH, hd) in the cache dtype. Nothing is written here: ``forward`` writes
+    every layer's rows into the stacked cache at ``cache_index`` after the
+    layer scan.
     """
     B, S, _ = x.shape
     scale = cfg.attn_scale or cfg.head_dim_ ** -0.5
@@ -86,33 +84,24 @@ def apply_attention(p, x, *, cfg, window: int = 0, positions=None,
     q = apply_rope(q, positions, rope_pct=cfg.rope_pct, theta=cfg.rope_theta)
     k = apply_rope(k, positions, rope_pct=cfg.rope_pct, theta=cfg.rope_theta)
 
-    new_cache = None
+    kv_rows = None
+    if cache is not None:
+        kv_rows = {"k": k.astype(cache["k"].dtype),
+                   "v": v.astype(cache["v"].dtype)}
     if mode == "decode":
-        idx = cache_index
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, idx, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, idx, 0, 0))
-        o = ops.decode_attention(q, ck, cv, window=window,
-                                 logit_softcap=cfg.attn_logit_softcap,
-                                 scale=scale, q_offset=idx, kv_len=idx + S)
-        new_cache = {"k": ck, "v": cv}
+        o = ops.decode_attention(q, cache["k"], cache["v"], kv_rows["k"],
+                                 kv_rows["v"], cache_index=cache_index,
+                                 window=window, scale=scale,
+                                 logit_softcap=cfg.attn_logit_softcap)
     else:
         o = ops.flash_attention(q, k, v, causal=causal, window=window,
                                 logit_softcap=cfg.attn_logit_softcap,
                                 scale=scale)
-        if mode == "prefill" and cache is not None:
-            new_cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)),
-            }
 
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     if cfg.use_bias:
         out = out + p["bo"]
-    return out, new_cache
+    return out, kv_rows
 
 
 def cross_kv_specs(cfg, d_src: int) -> dict:

@@ -6,8 +6,13 @@ per-layer parameters (compile-time O(1) in depth). Heterogeneous depth
 patterns (gemma2 local/global alternation, DeepSeek first-k-dense, Llama-3.2
 cross-attn interleave, Zamba2 shared block) become multi-block groups.
 
-Modes: ``train`` (no cache), ``prefill`` (flash attention + cache write at 0),
-``decode`` (single-token step over cache / SSM state).
+Modes: ``train`` (no cache), ``prefill`` (flash attention over the prompt),
+``decode`` (single-token step over the cache / SSM state). With a cache, the
+layer scan reads it and emits only what each layer computed: the attention
+blocks' new K/V rows, the SSM blocks' new state, the cross-attention K/V of a
+prefill. ``forward`` then writes the rows into the stacked cache at
+``cache_index`` and replaces the rest whole; the serving steps donate the
+cache, so the write happens in place.
 """
 from __future__ import annotations
 
@@ -40,6 +45,7 @@ class GroupDesc:
 
 
 A, F, S = BlockDesc("attn"), BlockDesc("ffn"), BlockDesc("ssm")
+ATTN_KINDS = ("attn", "parallel", "shared_attn")  # blocks with a KV cache
 
 
 def layer_plan(cfg) -> tuple[GroupDesc, ...]:
@@ -181,7 +187,7 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0,
     for i, gd in enumerate(layer_plan(cfg)):
         blocks = {}
         for j, b in enumerate(gd.blocks):
-            if b.kind in ("attn", "parallel", "shared_attn"):
+            if b.kind in ATTN_KINDS:
                 blocks[f"b{j}"] = attn_cache(gd.repeat)
             elif b.kind == "cross_attn":
                 blocks[f"b{j}"] = cross_cache(gd.repeat)
@@ -198,7 +204,8 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0,
 
 def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
                  cross_states, shared_params, positions):
-    """One residual block. Returns (x, new_cache|None, aux)."""
+    """One residual block. Returns (x, cache_out|None, aux): cache_out is
+    what ``_write_cache`` puts into this block's cache."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
 
@@ -240,7 +247,6 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         h = apply_norm(bp["norm"], x, cfg)
         if mode == "decode":
             kv = (cache["ck"], cache["cv"])
-            new_cache = cache
         else:
             k, v = compute_cross_kv(bp["cross_kv"], cross_states)
             kv = (k, v)
@@ -299,6 +305,25 @@ def _apply_group(gp, x, gd: GroupDesc, *, cfg, dist, mode, cache, cache_index,
     return x, aux, new_cache
 
 
+def _write_cache(gcache, emitted, gd: GroupDesc, cache_index):
+    """A group's cache after a step: the attention blocks' emitted K/V rows
+    (repeat, B, S, KVH, hd) written at ``cache_index``, any other emitted
+    state (SSM, a prefill's cross K/V) replacing the block's cache whole, and
+    blocks that emitted nothing (cross-attention in decode) unchanged."""
+    new = dict(gcache)
+    for j, b in enumerate(gd.blocks):
+        key = f"b{j}"
+        if emitted is None or key not in emitted:
+            continue
+        out = emitted[key]
+        if b.kind in ATTN_KINDS:
+            out = jax.tree.map(
+                lambda c, rows: jax.lax.dynamic_update_slice(
+                    c, rows, (0, 0, cache_index, 0, 0)), gcache[key], out)
+        new[key] = out
+    return new
+
+
 def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
             cache=None, cache_index=None, remat_policy=None,
             scan_unroll: int = 1):
@@ -350,8 +375,9 @@ def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
             shared_params=shared_params, positions=positions,
             remat_policy=remat_policy, unroll=scan_unroll)
         aux = aux + aux_g
-        if ncache is not None:
-            new_groups[f"g{i}"] = ncache
+        if gcache is not None:
+            new_groups[f"g{i}"] = _write_cache(gcache, ncache, gd,
+                                               cache_index)
 
     x = apply_norm(params["final_norm"], x, cfg)
     if cfg.tie_embeddings:

@@ -148,8 +148,38 @@ class TestOpsDispatch:
         q, k, v = _qkv(jax.random.PRNGKey(5), B, 1, Sk, H, KVH, D,
                        jnp.float32)
         idx = 40
-        got = ops.decode_attention(q, k, v, q_offset=idx, kv_len=idx + 1)
+        got = ops.decode_attention(q, k, v, k[:, idx:idx + 1],
+                                   v[:, idx:idx + 1], cache_index=idx)
         want = ref.mha_naive(q, k[:, :idx + 1], v[:, :idx + 1], causal=True,
                              q_offset=idx)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("H,KVH,window,softcap", [
+        (4, 4, 0, 0.0),      # MHA
+        (8, 2, 0, 0.0),      # GQA 4:1
+        (4, 2, 16, 0.0),     # sliding window
+        (4, 2, 0, 30.0),     # logit softcap
+    ])
+    @pytest.mark.parametrize("idx", [0, 37, 63])   # first, mid, last slot
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_decode_attention_vs_naive(self, H, KVH, window, softcap, idx,
+                                       dtype):
+        """Attention over the unwritten cache plus the new token == the naive
+        reference over the cache with the token written at ``cache_index``.
+        Every cache slot holds data, so a slot at or past the index that
+        leaked into the scores would show."""
+        B, T, D = 2, 64, 32
+        q, kc, vc = _qkv(jax.random.PRNGKey(idx), B, 1, T, H, KVH, D, dtype)
+        _, kn, vn = _qkv(jax.random.PRNGKey(idx + 1), B, 1, 1, H, KVH, D,
+                         dtype)
+        got = ops.decode_attention(q, kc, vc, kn, vn, cache_index=idx,
+                                   window=window, logit_softcap=softcap,
+                                   scale=0.3)
+        kw, vw = kc.at[:, idx].set(kn[:, 0]), vc.at[:, idx].set(vn[:, 0])
+        want = ref.mha_naive(q, kw, vw, causal=True, window=window,
+                             logit_softcap=softcap, scale=0.3, q_offset=idx,
+                             kv_len=jnp.full((B,), idx + 1))
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=TOL[dtype], rtol=TOL[dtype])

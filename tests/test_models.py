@@ -9,7 +9,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs.registry import ARCH_IDS, get_config
-from repro.models import common, moe as moe_mod
+from repro.models import common, moe as moe_mod, transformer
 from repro.models.common import apply_rope, rms_norm, softcap
 from repro.models.model_zoo import build_model
 from repro.runtime import serve as serve_rt
@@ -51,6 +51,42 @@ def test_decode_equals_forward(arch):
     np.testing.assert_allclose(np.asarray(last),
                                np.asarray(logits_full[:, -1]),
                                atol=DECODE_TOL, rtol=DECODE_TOL)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_decode_changes_only_the_cache_index_row(arch):
+    """A decode step writes the new token's K/V at ``cache_index`` and leaves
+    every other position of every attention cache, and the whole
+    cross-attention cache, bit for bit as it was; SSM state is replaced."""
+    cfg = get_config(arch, reduced=True)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, T, idx = 2, 12, 7
+    cache = model.init_cache(B, T, enc_len=model.enc_len_for(T))
+    leaves, tree = jax.tree.flatten(cache)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    cache = jax.tree.unflatten(tree, [
+        jax.random.normal(k, x.shape, jnp.float32).astype(x.dtype)
+        for k, x in zip(keys, leaves)])
+    decode = serve_rt.build_decode_step(model, serve_rt.ServeOptions())
+    tok = jnp.ones((B, 1), jnp.int32)
+    _, _, new = decode(params, cache, tok, jnp.asarray(idx, jnp.int32))
+    assert jax.tree.structure(new) == jax.tree.structure(cache)
+    rest = np.arange(T) != idx
+    for i, gd in enumerate(transformer.layer_plan(cfg)):
+        for j, b in enumerate(gd.blocks):
+            old_b = cache["groups"][f"g{i}"].get(f"b{j}")
+            new_b = new["groups"][f"g{i}"].get(f"b{j}")
+            if b.kind in transformer.ATTN_KINDS:
+                for name in ("k", "v"):
+                    o, n = np.asarray(old_b[name]), np.asarray(new_b[name])
+                    np.testing.assert_array_equal(n[:, :, rest],
+                                                  o[:, :, rest])
+                    assert not np.array_equal(n[:, :, idx], o[:, :, idx])
+            elif b.kind == "cross_attn":
+                for name in ("ck", "cv"):
+                    np.testing.assert_array_equal(np.asarray(new_b[name]),
+                                                  np.asarray(old_b[name]))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

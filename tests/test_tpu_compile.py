@@ -3,13 +3,16 @@
 The TPU compiler refuses what the Pallas interpreter accepts: blocks off the
 (8, 128) tiling, too much VMEM, a program larger than HBM. These tests compile
 the three kernels at the published widths of the models that use them, and the
-full-width deepseek-7b decode step, for one chip of a ``v5e:2x2`` topology.
+full-width deepseek-7b decode step, for one chip of a ``v5e:2x2`` topology;
+the decode step's compiled program must also write its donated cache in
+place.
 Nothing runs; only the compiler is exercised.
 
 The topology is described inside a fixture (never at import): only one
 process may load the TPU library, and every pytest worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,3 +106,31 @@ def test_deepseek_7b_decode_step_fits_one_v5e(one_chip):
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.output_size_in_bytes
     assert 12 * 2**30 < total < V5E_HBM_BYTES, total / 2**30
+
+
+def test_deepseek_7b_decode_writes_its_donated_cache_in_place(one_chip):
+    """Published widths, batch 8, a 192-position cache, donated as
+    ``ServeSession`` donates it. The layer scan reads the cache and emits only
+    the new token's K/V rows, which two in-place updates write: no second
+    cache in temporaries, and no copy of the stacked or one layer's cache."""
+    cfg = get_config("deepseek-7b")
+    model = build_model(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(one_chip, a.shape, a.dtype), tree)
+    B, T = 8, 192
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(abstract_cache(model, B, T))
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert cache_bytes == 754_974_720
+    compiled = jax.jit(build_decode_step(model, ServeOptions()),
+                       donate_argnums=(1,)).lower(
+        params, cache, _on(one_chip, (B, 1), jnp.int32),
+        _on(one_chip, (), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * cache_bytes
+    cache_shaped = re.compile(
+        rf"= bf16\[(\d+,)?{B},{T},{cfg.n_kv_heads},{cfg.head_dim_}\]")
+    copies = [line.strip() for line in compiled.as_text().splitlines()
+              if cache_shaped.search(line)
+              and (line.lstrip().startswith("%copy") or " copy(" in line)]
+    assert not copies, copies[:3]
