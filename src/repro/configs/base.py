@@ -23,6 +23,30 @@ class MoEConfig:
     router_aux_coef: float = 0.01   # load-balance auxiliary loss
     first_k_dense: int = 0          # leading dense layers (DeepSeek/Kimi style)
     d_ff_dense: int = 0             # hidden dim of those dense layers
+    # this chip's share under expert parallelism: experts
+    # [experts_offset, experts_offset + experts_held) of the num_experts the
+    # router scores (0 -> all of them)
+    experts_held: int = 0
+    experts_offset: int = 0
+    norm_topk_prob: bool = True     # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 applies it to
+    its rope dims. factor 0 -> plain rotary positions."""
+
+    factor: float = 0.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -68,6 +92,13 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0
     final_logit_softcap: float = 0.0
     attn_scale: float = 0.0     # 0 -> 1/sqrt(head_dim)
+    # multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0 caches one
+    # normed latent of this width plus one shared rope key per token
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: YarnConfig = field(default_factory=YarnConfig)
     use_bias: bool = False
     use_layernorm: bool = False  # False -> RMSNorm
     post_block_norm: bool = False  # gemma2 sandwich norms

@@ -21,7 +21,7 @@ CONFIG = ModelConfig(
     source="arXiv:2501.kimi2",
 )
 
-# drop-free capacity in the reduced config (see deepseek_moe_16b.py note)
+# drop-free capacity on the mesh path too (see deepseek_moe_16b.py note)
 REDUCED = CONFIG.replace(
     n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, vocab_size=256,
     head_dim=16,

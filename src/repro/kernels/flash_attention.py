@@ -9,6 +9,8 @@ Blockwise online-softmax attention tiled for VMEM/MXU:
   into VMEM per step; blocks are 128-aligned for the MXU.
 - GQA is expressed in the K/V index_map (q head -> kv head), so no KV
   repetition ever hits HBM.
+- V may be narrower than Q/K (DeepSeek-V2's latent attention: q/k 192, v
+  128); the accumulator and the output take V's width.
 
 The oracle is ``ref.mha_naive``; ``ops.flash_attention`` dispatches here on
 TPU and to ``ref.mha_chunked`` on CPU (same math, jnp scan).
@@ -80,9 +82,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
                            scale=None, q_offset=0, kv_valid=None,
                            block_q=128, block_k=128, interpret=False):
-    """q: (B, Sq, H, D); k, v: (B, Sk, KVH, D) -> (B, Sq, H, D)."""
+    """q, k: (B, S, H or KVH, D); v: (B, Sk, KVH, Dv) -> (B, Sq, H, Dv)."""
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
+    Dv = v.shape[-1]
     assert H % KVH == 0
     group = H // KVH
     scale = scale if scale is not None else D ** -0.5
@@ -116,16 +119,16 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
+            pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
+        out_specs=pl.BlockSpec((1, 1, block_q, Dv),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",

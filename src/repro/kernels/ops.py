@@ -8,12 +8,14 @@ O(block) intermediates. Kernel tests call the Pallas kernels themselves with
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_pallas
-from .moe_gmm import gmm_pallas
+from .moe_gmm import expert_ffn_pallas, gmm_pallas
 from .ssd_scan import ssd_scan_pallas
 
 
@@ -97,6 +99,52 @@ def decode_attention(q, k_cache, v_cache, k_new, v_new, *, cache_index,
         return o.reshape(B, S, H, D).astype(q.dtype)
 
 
+def mla_decode_attention(q_lat, q_pe, c_cache, pe_cache, c_new, pe_new, *,
+                         cache_index, scale):
+    """Absorbed latent attention (DeepSeek-V2) of S new tokens over a latent
+    cache they are not yet written into.
+
+    q_lat: (B, S, H, R), each head's no-rope query taken into the latent
+    space through W_uk; q_pe: (B, S, H, P) roped; c_cache: (B, T, R), the
+    normed latents; pe_cache: (B, T, P), the roped shared keys; c_new,
+    pe_new: (B, S, R), (B, S, P), the new tokens' own rows in the cache
+    dtype. A score is q_lat . c + q_pe . k_pe: one key of width R + P shared
+    by every head. Returns the latent output P . c, (B, S, H, R), fp32,
+    which W_uv takes out to each head's value width.
+
+    As ``decode_attention``: the cache's positions below ``cache_index`` and
+    the new tokens causally, one fp32 softmax over both, the cache
+    contracted in its stored dtype with fp32 accumulation.
+    """
+    S, T = q_lat.shape[1], c_cache.shape[1]
+    if _use_pallas():
+        mixed = functools.partial(jnp.einsum,
+                                  preferred_element_type=jnp.float32)
+    else:  # the CPU's batched dot takes no bf16 x bf16 -> f32
+        mixed = lambda spec, a, b: jnp.einsum(
+            spec, a.astype(jnp.float32), b.astype(jnp.float32))
+    with jax.named_scope("pk_mla_decode_attention"):
+        q_lat = q_lat.astype(c_cache.dtype)
+        q_pe = q_pe.astype(pe_cache.dtype)
+
+        def scores(c, pe, mask):
+            s = (mixed("bqhr,bkr->bhqk", q_lat, c)
+                 + mixed("bqhp,bkp->bhqk", q_pe, pe)) * scale
+            return jnp.where(mask, s, ref.NEG_INF)
+
+        i, t = jnp.arange(S)[:, None], jnp.arange(T)[None, :]
+        s_cache = scores(c_cache, pe_cache, t < cache_index)
+        s_new = scores(c_new, pe_new, i.T <= i)
+        top = jnp.maximum(s_cache.max(-1), s_new.max(-1))[..., None]
+        p_cache, p_new = jnp.exp(s_cache - top), jnp.exp(s_new - top)
+        total = p_cache.sum(-1, keepdims=True) + p_new.sum(-1, keepdims=True)
+
+        def pv(p, c):
+            return mixed("bhqk,bkr->bqhr", (p / total).astype(c.dtype), c)
+
+        return pv(p_cache, c_cache) + pv(p_new, c_new)
+
+
 def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk=128):
     with jax.named_scope("pk_ssd_scan"):
         if _use_pallas():
@@ -114,3 +162,15 @@ def gmm(x, w):
         if _use_pallas():
             return gmm_pallas(x, w)
         return ref.gmm_naive(x, w)
+
+
+def expert_ffn(x, w_gate, w_up, w_down, tile_group, active_tiles, group_rows,
+               *, block_m):
+    """SwiGLU of rows sorted by expert in whole tiles of ``block_m`` rows
+    (``moe_gmm.expert_ffn_pallas``); rows of unused tiles are undefined.
+    ``group_rows`` (G,) gives each expert's rows for the jnp twin."""
+    with jax.named_scope("pk_expert_ffn"):
+        if _use_pallas():
+            return expert_ffn_pallas(x, w_gate, w_up, w_down, tile_group,
+                                     active_tiles, block_m=block_m)
+        return ref.expert_ffn_ragged(x, w_gate, w_up, w_down, group_rows)

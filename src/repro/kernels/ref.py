@@ -47,13 +47,14 @@ def mha_naive(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
               scale=None, q_offset=0, kv_len=None):
     """Full-scores attention.
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KVH, D). GQA via head grouping.
-    q_offset: absolute position of q[0] (for decode).
+    q: (B, Sq, H, D); k: (B, Sk, KVH, D); v: (B, Sk, KVH, Dv). GQA via head
+    grouping. q_offset: absolute position of q[0] (for decode).
     kv_len: optional (B,) valid kv lengths (for cache decode).
-    Returns (B, Sq, H, D).
+    Returns (B, Sq, H, Dv).
     """
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
+    Dv = v.shape[-1]
     g = H // KVH
     scale = scale if scale is not None else D ** -0.5
     qf = q.astype(jnp.float32) * scale
@@ -67,7 +68,7 @@ def mha_naive(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
     s = jnp.where(m[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, Dv).astype(q.dtype)
 
 
 def mha_chunked(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
@@ -79,6 +80,7 @@ def mha_chunked(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
     """
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
+    Dv = v.shape[-1]
     g = H // KVH
     scale = scale if scale is not None else D ** -0.5
     block_k = min(block_k, Sk)
@@ -91,7 +93,7 @@ def mha_chunked(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, KVH, g, D)
     q_pos = jnp.arange(Sq) + q_offset
     kb = k.reshape(B, nblk, block_k, KVH, D).astype(jnp.float32)
-    vb = v.reshape(B, nblk, block_k, KVH, D).astype(jnp.float32)
+    vb = v.reshape(B, nblk, block_k, KVH, Dv).astype(jnp.float32)
 
     def step(carry, blk):
         m_run, l_run, acc = carry
@@ -113,13 +115,13 @@ def mha_chunked(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
 
     m0 = jnp.full((B, KVH, g, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KVH, g, Sq), jnp.float32)
-    a0 = jnp.zeros((B, KVH, g, Sq, D), jnp.float32)
+    a0 = jnp.zeros((B, KVH, g, Sq, Dv), jnp.float32)
     starts = jnp.arange(nblk) * block_k
     (m_f, l_f, acc), _ = jax.lax.scan(
         step, (m0, l0, a0),
         (kb.transpose(1, 0, 2, 3, 4), vb.transpose(1, 0, 2, 3, 4), starts))
     o = acc / jnp.maximum(l_f, 1e-30)[..., None]
-    o = o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, D)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dv)
     return o.astype(q.dtype)
 
 
@@ -240,3 +242,14 @@ def gmm_naive(x, w):
     """x: (E, C, d), w: (E, d, f) -> (E, C, f) with fp32 accumulation."""
     return jnp.einsum("ecd,edf->ecf", x, w,
                       preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def expert_ffn_ragged(x, w_gate, w_up, w_down, group_rows):
+    """SwiGLU of each row under its group's weights: x (M, d) rows sorted
+    by group, ``group_rows`` (G,) rows of each group in order; rows past
+    them give 0. Twin of ``moe_gmm.expert_ffn_pallas``."""
+    def mm(a, w):  # fp32 operands: the CPU's ragged dot takes no bf16 -> f32
+        return jax.lax.ragged_dot(a.astype(jnp.float32),
+                                  w.astype(jnp.float32), group_rows)
+    h = jax.nn.silu(mm(x, w_gate)) * mm(x, w_up)
+    return mm(h.astype(x.dtype), w_down).astype(x.dtype)

@@ -160,15 +160,51 @@ def rope_freqs(head_dim: int, rope_pct: float, theta: float):
     return inv, rot_dim
 
 
-def apply_rope(x, positions, *, rope_pct: float = 1.0, theta: float = 10000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn):
+    """YaRN's frequencies for ``dim`` rotated dims, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: the plain frequencies
+    (extrapolated) below the correction range found from ``beta_fast`` and
+    ``beta_slow`` at the original context, divided by ``factor``
+    (interpolated) above it, and a linear ramp between."""
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    interp = extra / yarn.factor
+
+    def corr(rotations):
+        return dim * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    high = high + 0.001 if high == low else high
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    mask = 1.0 - jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return interp * (1.0 - mask) + extra * mask
+
+
+def apply_rope(x, positions, *, rope_pct: float = 1.0, theta: float = 10000.0,
+               yarn=None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32. With a
+    ``yarn`` config of nonzero factor, its frequencies (every dim rotated)
+    and its cos/sin scale mscale / mscale_all_dim."""
     head_dim = x.shape[-1]
-    inv, rot_dim = rope_freqs(head_dim, rope_pct, theta)
+    if yarn is not None and yarn.factor:
+        inv, rot_dim = yarn_inv_freq(head_dim, theta, yarn), head_dim
+        amp = (yarn_mscale(yarn.factor, yarn.mscale)
+               / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    else:
+        inv, rot_dim = rope_freqs(head_dim, rope_pct, theta)
+        amp = 1.0
     if rot_dim == 0:
         return x
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., seq, rot/2)
     cos = jnp.cos(ang)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(ang)[..., :, None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x_rot, x_pass = x[..., :rot_dim], x[..., rot_dim:]
     x1, x2 = x_rot[..., 0::2], x_rot[..., 1::2]
     y1 = x1 * cos - x2 * sin

@@ -1,18 +1,29 @@
-"""Fine-grained mixture-of-experts (DeepSeekMoE / Kimi-K2 style).
+"""Fine-grained mixture-of-experts (DeepSeekMoE / DeepSeek-V2 / Kimi-K2).
 
-Token-choice top-k routing with fixed capacity, sort-based dispatch, and the
-Pallas grouped matmul (``kernels.ops.gmm``) for expert FFNs.
+Token-choice top-k routing: softmax over every expert's router score in
+fp32, greedy top-k, the weights renormalised over the k (``norm_topk_prob``)
+or not, times ``routed_scaling_factor``; the shared experts (one SwiGLU of
+``num_shared`` experts' width) are added to every token.
 
-Distribution (TPU-native EP): the expert interior runs under ``jax.shard_map``
-— each data shard routes its local tokens, builds an (E, C_local, d) dispatch
-buffer, and a **tiled all-to-all over the model axis** exchanges it for an
-(E_local, C_local * ep, d) buffer (the DeepSeek-EP dispatch pattern mapped to
-``jax.lax.all_to_all``). Expert weights live sharded on the model axis;
-optionally they are additionally FSDP-sharded over the data axis and
-all-gathered just-in-time inside the shard_map body.
+One device (``dist.mesh is None``) holds experts ``[experts_offset,
+experts_offset + experts_held)`` of the ``num_experts`` the router scores:
+its share under expert parallelism. It computes only its experts' part of
+the routed result, and drops nothing: the assignments that fall on its
+experts are sorted by expert into whole row tiles, and one grouped kernel
+(``kernels.ops.expert_ffn``, SwiGLU only) runs each expert over its rows. Its
+buffers are sized from the token count (all of a token's k choices could
+fall here), never from experts x tokens, and long inputs go through in
+chunks of ``CHUNK_TOKENS``. What the absent experts add lies on other
+devices; nothing here stands in for them or their exchange.
 
-On a single device (smoke tests) the same local functions run without
-collectives.
+Distribution (TPU-native EP, every expert sharded over the model axis): the
+expert interior runs under ``jax.shard_map`` — each data shard routes its
+local tokens, builds an (E, C_local, d) capacity buffer (assignments past a
+capacity are dropped), and a **tiled all-to-all over the model axis**
+exchanges it for an (E_local, C_local * ep, d) buffer (the DeepSeek-EP
+dispatch pattern mapped to ``jax.lax.all_to_all``). Expert weights live
+sharded on the model axis; optionally they are additionally FSDP-sharded
+over the data axis and all-gathered just-in-time inside the shard_map body.
 """
 from __future__ import annotations
 
@@ -53,11 +64,11 @@ def moe_specs(cfg) -> dict:
     spec = {
         "router": ParamSpec((d, m.num_experts), ("router_in", "experts_in"),
                             dtype=jnp.float32),
-        "w_gate": ParamSpec((m.num_experts, d, m.d_ff_expert),
+        "w_gate": ParamSpec((m.held, d, m.d_ff_expert),
                             ("experts", "embed", "expert_mlp")),
-        "w_up": ParamSpec((m.num_experts, d, m.d_ff_expert),
+        "w_up": ParamSpec((m.held, d, m.d_ff_expert),
                           ("experts", "embed", "expert_mlp")),
-        "w_down": ParamSpec((m.num_experts, m.d_ff_expert, d),
+        "w_down": ParamSpec((m.held, m.d_ff_expert, d),
                             ("experts", "expert_mlp", "embed")),
     }
     if m.num_shared:
@@ -80,19 +91,24 @@ def _capacity(n_tokens: int, cfg, cap: int = 0) -> int:
 
 
 def _route(x2d, router_w, cfg):
-    """Top-k routing. x2d: (T, d). Returns topk_idx (T,k), weights (T,k), aux."""
+    """Top-k routing over all ``num_experts``. x2d: (T, d). Returns
+    topk_idx (T,k), fp32 weights (T,k), aux."""
     m = cfg.moe
-    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32), router_w)
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                        router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     topk_p, topk_idx = jax.lax.top_k(probs, m.top_k)
-    topk_w = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+    topk_w = topk_p
+    if m.norm_topk_prob:
+        topk_w = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+    topk_w = topk_w * m.routed_scaling_factor
     # load-balance aux (Switch-style): E * sum_e f_e * p_e
     E = m.num_experts
     f_e = jnp.zeros((E,), jnp.float32).at[topk_idx.reshape(-1)].add(
         1.0 / (topk_idx.size))
     p_e = probs.mean(0)
     aux = E * jnp.sum(f_e * p_e) * m.router_aux_coef
-    return topk_idx, topk_w.astype(x2d.dtype), aux
+    return topk_idx, topk_w, aux
 
 
 def _dispatch_indices(topk_idx, E: int, C: int):
@@ -131,22 +147,95 @@ def _expert_ffn(x_e, wg, wu, wd, cfg):
                     ).astype(x_e.dtype), wd)
 
 
-def _moe_local(x2d, p, cfg):
-    """Single-shard MoE: route -> dispatch -> gmm -> combine."""
-    T, d = x2d.shape
+CHUNK_TOKENS = 8192  # tokens per pass of the one-device expert layer
+
+
+def _row_tile(n_tokens: int, cfg) -> int:
+    """Rows of one tile of the grouped kernel: 128 (the MXU's side) where an
+    expert expects a tile's worth of rows under uniform routing, else 16
+    (decode), so that an expert with a few rows pads to 16 and not 128."""
     m = cfg.moe
-    C = _capacity(T, cfg)
-    topk_idx, topk_w, aux = _route(x2d, p["router"], cfg)
-    gather_idx, inv = _dispatch_indices(topk_idx, m.num_experts, C)
+    return 128 if n_tokens * m.top_k >= 128 * m.num_experts else 16
+
+
+def _dispatch_held(topk_idx, cfg, bm: int):
+    """The assignments that fall on this device's experts, sorted by expert
+    into whole tiles of ``bm`` rows, the tiles in use first.
+
+    Returns slot (T*k,) int32 (buffer row of each assignment, M where its
+    expert is not held here), row_token (M,) int32 (token of each buffer
+    row, T for padding), tile_group (M/bm,) int32, active (1,) int32 tiles
+    in use, group_rows (held,) int32 rows (padding included) of each
+    expert, and the number of held assignments. M = (ceil(T*kk/bm) + held)
+    * bm with kk = min(k, held): every choice of every token fits."""
+    m = cfg.moe
+    T, k = topk_idx.shape
+    n = m.held
+    n_tiles = -(-T * min(k, n) // bm) + n
+    M = n_tiles * bm
+    e = topk_idx.reshape(-1) - m.experts_offset
+    grp = jnp.where((e >= 0) & (e < n), e, n)            # n: held elsewhere
+    counts = jnp.zeros((n + 1,), jnp.int32).at[grp].add(1)
+    tiles = -(-counts[:n] // bm)
+    tile_end = jnp.cumsum(tiles)
+    first_row = (tile_end - tiles) * bm
+    order = jnp.argsort(grp, stable=True).astype(jnp.int32)
+    g_sorted = grp[order]
+    rank = jnp.arange(T * k, dtype=jnp.int32) - (
+        jnp.cumsum(counts) - counts)[g_sorted]
+    dest = jnp.where(g_sorted < n,
+                     first_row[jnp.minimum(g_sorted, n - 1)] + rank, M)
+    slot = jnp.zeros((T * k,), jnp.int32).at[order].set(dest)
+    row_token = jnp.full((M,), T, jnp.int32).at[dest].set(order // k,
+                                                         mode="drop")
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
+        n - 1).astype(jnp.int32)
+    return (slot, row_token, tile_group, tile_end[-1:], tiles * bm,
+            counts[:n].sum())
+
+
+def _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg):
+    """This device's experts' part of the routed result for one chunk of
+    tokens. Returns (out (T, d), [held assignments, rows computed, held
+    experts with a row]): the kernel reads each such expert's weights
+    once, and no other's."""
+    T, d = x2d.shape
+    bm = _row_tile(T, cfg)
+    slot, row_token, tile_group, active, group_rows, routed = _dispatch_held(
+        topk_idx, cfg, bm)
     x_pad = jnp.concatenate([x2d, jnp.zeros((1, d), x2d.dtype)], 0)
-    x_e = x_pad[gather_idx]                              # (E, C, d)
-    y_e = _expert_ffn(x_e, p["w_gate"], p["w_up"], p["w_down"], cfg)
-    y_flat = jnp.concatenate(
-        [y_e.reshape(m.num_experts * C, d), jnp.zeros((1, d), y_e.dtype)], 0)
-    y_tok = y_flat[inv].reshape(T, m.top_k, d)           # dropped -> zeros
+    y = ops.expert_ffn(x_pad[row_token], p["w_gate"], p["w_up"],
+                       p["w_down"], tile_group, active, group_rows,
+                       block_m=bm)
+    y_pad = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)], 0)
+    y_tok = y_pad[slot].reshape(T, topk_idx.shape[1], d)  # elsewhere -> 0
     out = jnp.einsum("tkd,tk->td", y_tok.astype(jnp.float32),
-                     topk_w.astype(jnp.float32)).astype(x2d.dtype)
-    return out, aux
+                     topk_w).astype(x2d.dtype)
+    hit = (group_rows > 0).sum(dtype=jnp.int32)
+    return out, jnp.stack([routed, active[0] * bm, hit])
+
+
+def _moe_local(x2d, p, cfg):
+    """One device's MoE: route over all experts, then this device's experts
+    over their rows, dropless, in chunks of ``CHUNK_TOKENS`` tokens. Returns
+    (out, aux, rows): rows = int32 [assignments that fell on the held
+    experts, rows the grouped kernel computed, held experts it read the
+    weights of], summed over the chunks."""
+    T, d = x2d.shape
+    topk_idx, topk_w, aux = _route(x2d, p["router"], cfg)
+    if T <= CHUNK_TOKENS:
+        out, rows = _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg)
+        return out, aux, rows
+    n, pad = -(-T // CHUNK_TOKENS), -T % CHUNK_TOKENS
+    chunks = lambda a, fill: jnp.pad(
+        a, ((0, pad),) + ((0, 0),) * (a.ndim - 1), constant_values=fill
+    ).reshape(n, CHUNK_TOKENS, *a.shape[1:])
+    # padding tokens choose an expert no device holds
+    out, rows = jax.lax.map(
+        lambda c: _moe_held_chunk(*c, p, cfg),
+        (chunks(x2d, 0), chunks(topk_idx, -1), chunks(topk_w, 0)))
+    return out.reshape(n * CHUNK_TOKENS, d)[:T], aux, rows.sum(0)
 
 
 def _moe_ep_body(x_local, router_w, wg, wu, wd, *, cfg, dist: DistContext):
@@ -193,14 +282,22 @@ def _moe_ep_body(x_local, router_w, wg, wu, wd, *, cfg, dist: DistContext):
 
 
 def apply_moe(p, x, *, cfg, dist: DistContext = LOCAL):
-    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
+    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar, rows int32[3]):
+    rows counts, on one device, the assignments that fell on its experts,
+    the rows its grouped kernel computed and the experts whose weights the
+    kernel read (zeros on the mesh path)."""
     B, S, d = x.shape
     x2d = x.reshape(B * S, d)
     m = cfg.moe
 
+    rows = jnp.zeros((3,), jnp.int32)
     if dist.mesh is None or not dist.ep:
-        out, aux = _moe_local(x2d, p, cfg)
+        out, aux, rows = _moe_local(x2d, p, cfg)
     else:
+        if m.held != m.num_experts:
+            raise NotImplementedError(
+                "the expert-parallel path shards all num_experts over the "
+                "model axis; a config holding a share runs on one device")
         if dist.expert_tp:     # 2D: EP over model, f TP'd over data
             ep_w_spec = P(dist.model_axis, None, dist.data_axes)
             ep_wd_spec = P(dist.model_axis, dist.data_axes, None)
@@ -222,4 +319,4 @@ def apply_moe(p, x, *, cfg, dist: DistContext = LOCAL):
     if m.num_shared:
         from .ffn import apply_ffn
         out = out + apply_ffn(p["shared"], x, cfg=cfg).reshape(B * S, d)
-    return out.reshape(B, S, d), aux
+    return out.reshape(B, S, d), aux, rows

@@ -9,10 +9,14 @@ cross-attn interleave, Zamba2 shared block) become multi-block groups.
 Modes: ``train`` (no cache), ``prefill`` (flash attention over the prompt),
 ``decode`` (single-token step over the cache / SSM state). With a cache, the
 layer scan reads it and emits only what each layer computed: the attention
-blocks' new K/V rows, the SSM blocks' new state, the cross-attention K/V of a
-prefill. ``forward`` then writes the rows into the stacked cache at
-``cache_index`` and replaces the rest whole; the serving steps donate the
-cache, so the write happens in place.
+blocks' new K/V rows (latent attention's c_kv / k_pe rows), the SSM blocks'
+new state, the cross-attention K/V of a prefill. ``forward`` then writes the
+rows into the stacked cache at ``cache_index`` and replaces the rest whole;
+the serving steps donate the cache, so the write happens in place. A model
+with expert layers also carries ``moe_rows`` in its cache: its experts'
+routed rows, computed rows and weight reads, summed over layers and steps,
+one row for prefill and one for decode. Prefill
+evaluates the head at the last position only.
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ from .attention import (apply_attention, attention_specs, compute_cross_kv,
                         cross_kv_specs)
 from .common import (ParamSpec, apply_norm, norm_spec, softcap)
 from .ffn import apply_ffn, ffn_specs
+from .mla import apply_mla, mla_specs
 from .moe import DistContext, LOCAL, apply_moe, moe_specs
 from .ssm import (apply_ssm, apply_ssm_decode, init_ssm_state,
                   ssm_specs)
@@ -32,7 +37,7 @@ from .ssm import (apply_ssm, apply_ssm_decode, init_ssm_state,
 
 @dataclass(frozen=True)
 class BlockDesc:
-    kind: str            # attn | ffn | moe | ssm | cross_attn | parallel | shared_attn
+    kind: str            # attn | mla | ffn | moe | ssm | cross_attn | parallel | shared_attn
     window: int = 0
     d_ff: int = 0        # ffn width override (0 -> cfg.d_ff)
     causal: bool = True
@@ -45,7 +50,7 @@ class GroupDesc:
 
 
 A, F, S = BlockDesc("attn"), BlockDesc("ffn"), BlockDesc("ssm")
-ATTN_KINDS = ("attn", "parallel", "shared_attn")  # blocks with a KV cache
+ATTN_KINDS = ("attn", "mla", "parallel", "shared_attn")  # blocks with a KV cache
 
 
 def layer_plan(cfg) -> tuple[GroupDesc, ...]:
@@ -74,12 +79,13 @@ def layer_plan(cfg) -> tuple[GroupDesc, ...]:
                            A, F)),)
     if cfg.family == "moe":
         m = cfg.moe
+        attn = BlockDesc("mla") if cfg.kv_lora_rank else A
         groups = []
         if m.first_k_dense:
             groups.append(GroupDesc(
-                m.first_k_dense, (A, BlockDesc("ffn", d_ff=m.d_ff_dense))))
+                m.first_k_dense, (attn, BlockDesc("ffn", d_ff=m.d_ff_dense))))
         groups.append(GroupDesc(cfg.n_layers - m.first_k_dense,
-                                (A, BlockDesc("moe"))))
+                                (attn, BlockDesc("moe"))))
         return tuple(groups)
     # plain dense decoder
     w = cfg.sliding_window
@@ -105,6 +111,8 @@ def _block_specs(cfg, b: BlockDesc) -> dict:
         spec["post_norm"] = norm_spec(cfg)
     if b.kind == "attn":
         spec["attn"] = attention_specs(cfg)
+    elif b.kind == "mla":
+        spec["attn"] = mla_specs(cfg)
     elif b.kind == "ffn":
         spec["ffn"] = ffn_specs(cfg, d_ff=b.d_ff or cfg.d_ff)
     elif b.kind == "moe":
@@ -175,6 +183,11 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0,
         shape = (repeat, batch, max_len, kvh, hd)
         return {"k": jnp.zeros(shape, kv_dtype), "v": jnp.zeros(shape, kv_dtype)}
 
+    def mla_cache(repeat):
+        shape = (repeat, batch, max_len)
+        return {"c_kv": jnp.zeros(shape + (cfg.kv_lora_rank,), kv_dtype),
+                "k_pe": jnp.zeros(shape + (cfg.qk_rope_head_dim,), kv_dtype)}
+
     def cross_cache(repeat):
         shape = (repeat, batch, enc_len, kvh, hd)
         return {"ck": jnp.zeros(shape, kv_dtype), "cv": jnp.zeros(shape, kv_dtype)}
@@ -187,14 +200,23 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0,
     for i, gd in enumerate(layer_plan(cfg)):
         blocks = {}
         for j, b in enumerate(gd.blocks):
-            if b.kind in ATTN_KINDS:
+            if b.kind == "mla":
+                blocks[f"b{j}"] = mla_cache(gd.repeat)
+            elif b.kind in ATTN_KINDS:
                 blocks[f"b{j}"] = attn_cache(gd.repeat)
             elif b.kind == "cross_attn":
                 blocks[f"b{j}"] = cross_cache(gd.repeat)
             elif b.kind == "ssm":
                 blocks[f"b{j}"] = ssm_cache(gd.repeat)
         groups[f"g{i}"] = blocks
-    return {"groups": groups}
+    cache = {"groups": groups}
+    if _has_moe(cfg):
+        cache["moe_rows"] = jnp.zeros((2, 3), jnp.int32)  # prefill, decode
+    return cache
+
+
+def _has_moe(cfg) -> bool:
+    return any(b.kind == "moe" for gd in layer_plan(cfg) for b in gd.blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -204,10 +226,11 @@ def init_cache(cfg, batch: int, max_len: int, *, enc_len: int = 0,
 
 def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
                  cross_states, shared_params, positions):
-    """One residual block. Returns (x, cache_out|None, aux): cache_out is
-    what ``_write_cache`` puts into this block's cache."""
+    """One residual block. Returns (x, cache_out|None, aux, rows|None):
+    cache_out is what ``_write_cache`` puts into this block's cache, rows an
+    expert layer's [routed rows, computed rows, experts read] counts."""
     aux = jnp.zeros((), jnp.float32)
-    new_cache = None
+    new_cache = rows = None
 
     def maybe_post(out, p):
         return apply_norm(p["post_norm"], out, cfg) if cfg.post_block_norm else out
@@ -222,6 +245,12 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         if b.kind == "shared_attn":  # zamba2 shared block = attn + mlp
             h = apply_norm(p["ffn_norm"], x, cfg)
             x = x + apply_ffn(p["ffn"], h, cfg=cfg)
+    elif b.kind == "mla":
+        h = apply_norm(bp["norm"], x, cfg)
+        out, new_cache = apply_mla(bp["attn"], h, cfg=cfg, positions=positions,
+                                   cache=cache, cache_index=cache_index,
+                                   mode=mode)
+        x = x + maybe_post(out, bp)
     elif b.kind == "parallel":  # command-r: one norm, attn || ffn
         h = apply_norm(bp["norm"], x, cfg)
         out_a, new_cache = apply_attention(
@@ -234,7 +263,7 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         x = x + maybe_post(apply_ffn(bp["ffn"], h, cfg=cfg), bp)
     elif b.kind == "moe":
         h = apply_norm(bp["norm"], x, cfg)
-        out, aux = apply_moe(bp["moe"], h, cfg=cfg, dist=dist)
+        out, aux, rows = apply_moe(bp["moe"], h, cfg=cfg, dist=dist)
         x = x + maybe_post(out, bp)
     elif b.kind == "ssm":
         h = apply_norm(bp["norm"], x, cfg)
@@ -258,7 +287,7 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         x = x + maybe_post(out, bp)
     else:
         raise ValueError(b.kind)
-    return x, new_cache, aux
+    return x, new_cache, aux, rows
 
 
 def _maybe_remat(body, remat_policy: str | None, mode: str):
@@ -279,35 +308,41 @@ def _maybe_remat(body, remat_policy: str | None, mode: str):
 def _apply_group(gp, x, gd: GroupDesc, *, cfg, dist, mode, cache, cache_index,
                  cross_states, shared_params, positions, remat_policy=None,
                  unroll: int = 1):
-    """Scan the group body over its ``repeat`` stacked layers."""
+    """Scan the group body over its ``repeat`` stacked layers. Returns (x,
+    aux, emitted cache, expert rows summed over the layers, or None where
+    the group has no expert layer)."""
 
     def body(carry, xs):
-        h, aux = carry
+        h, aux, rows = carry
         bp_all, bc_all = xs
         new_caches = {}
         for j, b in enumerate(gd.blocks):
             key = f"b{j}"
             bc = None if bc_all is None else bc_all.get(key)
-            h, nc, aux_j = _apply_block(
+            h, nc, aux_j, rows_j = _apply_block(
                 bp_all[key], h, b, cfg=cfg, dist=dist, mode=mode, cache=bc,
                 cache_index=cache_index, cross_states=cross_states,
                 shared_params=shared_params, positions=positions)
             if nc is not None:
                 new_caches[key] = nc
             aux = aux + aux_j
-        return (h, aux), (new_caches if new_caches else None)
+            if rows_j is not None:
+                rows = rows + rows_j
+        return (h, aux, rows), (new_caches if new_caches else None)
 
     body = _maybe_remat(body, remat_policy, mode)
 
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       (gp, cache),
-                                       unroll=min(unroll, gd.repeat) or 1)
-    return x, aux, new_cache
+    rows0 = (jnp.zeros((3,), jnp.int32)
+             if any(b.kind == "moe" for b in gd.blocks) else None)
+    (x, aux, rows), new_cache = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), rows0), (gp, cache),
+        unroll=min(unroll, gd.repeat) or 1)
+    return x, aux, new_cache, rows
 
 
 def _write_cache(gcache, emitted, gd: GroupDesc, cache_index):
-    """A group's cache after a step: the attention blocks' emitted K/V rows
-    (repeat, B, S, KVH, hd) written at ``cache_index``, any other emitted
+    """A group's cache after a step: the attention blocks' emitted rows
+    (repeat, B, S, ...) written at ``cache_index``, any other emitted
     state (SSM, a prefill's cross K/V) replacing the block's cache whole, and
     blocks that emitted nothing (cross-attention in decode) unchanged."""
     new = dict(gcache)
@@ -319,7 +354,8 @@ def _write_cache(gcache, emitted, gd: GroupDesc, cache_index):
         if b.kind in ATTN_KINDS:
             out = jax.tree.map(
                 lambda c, rows: jax.lax.dynamic_update_slice(
-                    c, rows, (0, 0, cache_index, 0, 0)), gcache[key], out)
+                    c, rows, (0, 0, cache_index) + (0,) * (rows.ndim - 3)),
+                gcache[key], out)
         new[key] = out
     return new
 
@@ -331,7 +367,8 @@ def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
 
     inputs: {'tokens': (B, S) int32, optional 'frames': (B, S_enc, d_model)
     (encdec stub frontend), optional 'patches': (B, P, d_vision) (vlm stub)}.
-    Returns (logits, new_cache|None, aux_loss).
+    Returns (logits, new_cache|None, aux_loss); in ``prefill`` mode the
+    logits of the last position only, (B, 1, vocab).
     """
     tokens = inputs["tokens"]
     B, Sq = tokens.shape
@@ -356,7 +393,7 @@ def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
         h = jnp.einsum("bse,ed->bsd", inputs["frames"].astype(x.dtype),
                        enc["in_proj"].astype(x.dtype))
         for i, gd in enumerate(encoder_plan(cfg)):
-            h, _, _ = _apply_group(
+            h, _, _, _ = _apply_group(
                 enc["groups"][f"g{i}"], h, gd, cfg=cfg, dist=dist,
                 mode="train", cache=None, cache_index=None,
                 cross_states=None, shared_params=None,
@@ -366,24 +403,33 @@ def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
 
     shared_params = params.get("shared")
     aux = jnp.zeros((), jnp.float32)
-    new_groups = {}
+    new_groups, rows = {}, None
     for i, gd in enumerate(layer_plan(cfg)):
         gcache = None if cache is None else cache["groups"].get(f"g{i}")
-        x, aux_g, ncache = _apply_group(
+        x, aux_g, ncache, rows_g = _apply_group(
             params["groups"][f"g{i}"], x, gd, cfg=cfg, dist=dist, mode=mode,
             cache=gcache, cache_index=cache_index, cross_states=cross_states,
             shared_params=shared_params, positions=positions,
             remat_policy=remat_policy, unroll=scan_unroll)
         aux = aux + aux_g
+        if rows_g is not None:
+            rows = rows_g if rows is None else rows + rows_g
         if gcache is not None:
             new_groups[f"g{i}"] = _write_cache(gcache, ncache, gd,
                                                cache_index)
 
+    if mode == "prefill":
+        x = x[:, -1:]
     x = apply_norm(params["final_norm"], x, cfg)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     logits = softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
-    new_cache = {"groups": new_groups} if cache is not None else None
+    new_cache = None
+    if cache is not None:
+        new_cache = {"groups": new_groups}
+        if "moe_rows" in cache:
+            new_cache["moe_rows"] = cache["moe_rows"].at[
+                int(mode == "decode")].add(rows)
     return logits, new_cache, aux

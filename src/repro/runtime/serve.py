@@ -145,17 +145,26 @@ class ServeSession:
 
         Under a profiler session each call records the spans ``serve.*``
         of one request (``tracing``): the spans time the host's dispatch,
-        the device runs behind them."""
+        the device runs behind them. A model with expert layers adds
+        ``experts_held`` to ``serve.generate`` and, read once at the end of
+        the call, the cache's counters ``moe_rows_routed`` (assignments that
+        fell on the held experts), ``moe_rows_computed`` (rows the grouped
+        kernel computed) and ``moe_experts_read`` (held experts with a row,
+        whose weights the kernel read, once a kernel call), summed over
+        layers, prefill and decode, and the decode steps' part of the first
+        and last, ``moe_decode_rows_routed`` and ``moe_decode_experts_read``."""
         B, S = prompts.shape
+        moe = self.model.cfg.moe
+        held = {"experts_held": moe.held} if moe.num_experts else {}
         with tracing.span("serve.generate", batch=B, prompt_len=S,
-                          new_tokens=max_new_tokens) as call:
+                          new_tokens=max_new_tokens, **held) as call:
             enc_len = self.model.enc_len_for(S)
             with tracing.span("serve.init_cache"):
                 cache = self.model.init_cache(B, S + max_new_tokens,
                                               enc_len=enc_len)
             if call is not None:
-                call.set(cache_bytes=sum(x.nbytes
-                                         for x in jax.tree.leaves(cache)))
+                call.set(cache_bytes=sum(
+                    x.nbytes for x in jax.tree.leaves(cache["groups"])))
             inputs = {"tokens": prompts, **(extras or {})}
             with tracing.span("serve.prefill"):
                 last_logits, cache = self.prefill(self.params, inputs, cache)
@@ -169,4 +178,13 @@ class ServeSession:
                     idx = idx + 1
                 out.append(tok)
             with tracing.span("serve.concat"):
-                return jnp.concatenate(out, axis=1)
+                tokens = jnp.concatenate(out, axis=1)
+            if call is not None and "moe_rows" in cache:
+                prefill, decode = jax.device_get(cache["moe_rows"]).tolist()
+                routed, computed, read = (p + d for p, d in zip(prefill,
+                                                                 decode))
+                call.set(moe_rows_routed=routed, moe_rows_computed=computed,
+                         moe_experts_read=read,
+                         moe_decode_rows_routed=decode[0],
+                         moe_decode_experts_read=decode[2])
+            return tokens

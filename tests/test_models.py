@@ -1,5 +1,6 @@
 """Model-level correctness: KV-cache decode == full forward, RoPE/norm
 properties, MoE routing invariants."""
+import dataclasses
 import math
 
 import jax
@@ -78,7 +79,7 @@ def test_decode_changes_only_the_cache_index_row(arch):
             old_b = cache["groups"][f"g{i}"].get(f"b{j}")
             new_b = new["groups"][f"g{i}"].get(f"b{j}")
             if b.kind in transformer.ATTN_KINDS:
-                for name in ("k", "v"):
+                for name in old_b:  # k, v; or MLA's c_kv, k_pe
                     o, n = np.asarray(old_b[name]), np.asarray(new_b[name])
                     np.testing.assert_array_equal(n[:, :, rest],
                                                   o[:, :, rest])
@@ -169,11 +170,23 @@ class TestMoE:
         return cfg, p, x
 
     def test_router_topk_weights_normalized(self):
+        """norm_topk_prob renormalises the k weights; deepseek-moe-16b's
+        published false leaves them the softmax's own scores."""
         cfg, p, x = self._setup()
-        idx, w, aux = moe_mod._route(x, p["router"], cfg)
+        norm = cfg.replace(moe=dataclasses.replace(cfg.moe,
+                                                   norm_topk_prob=True))
+        idx, w, aux = moe_mod._route(x, p["router"], norm)
         assert idx.shape == (64, cfg.moe.top_k)
         np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-3)
         assert float(aux) > 0
+        idx_raw, w_raw, _ = moe_mod._route(x, p["router"], cfg)
+        probs = jax.nn.softmax(x @ p["router"], axis=-1)
+        np.testing.assert_array_equal(np.asarray(idx_raw), np.asarray(idx))
+        np.testing.assert_allclose(
+            np.asarray(w_raw),
+            np.take_along_axis(np.asarray(probs), np.asarray(idx), -1),
+            rtol=1e-5)
+        assert float(w_raw.sum(-1).max()) < 1.0
 
     def test_dispatch_preserves_tokens(self):
         """Sort-based dispatch: every kept assignment lands in exactly one
@@ -200,7 +213,7 @@ class TestMoE:
 
     def test_local_moe_finite_and_shaped(self):
         cfg, p, x = self._setup()
-        out, aux = moe_mod._moe_local(x, p, cfg)
+        out, aux, _ = moe_mod._moe_local(x, p, cfg)
         assert out.shape == x.shape
         assert bool(jnp.all(jnp.isfinite(out)))
 
@@ -209,7 +222,7 @@ class TestMoE:
         cfg, p, x = self._setup(T=16)
         cfg = cfg.replace(moe=cfg.moe.__class__(
             **{**cfg.moe.__dict__, "capacity_factor": 64.0}))
-        out, _ = moe_mod._moe_local(x, p, cfg)
+        out, _, _ = moe_mod._moe_local(x, p, cfg)
         idx, w, _ = moe_mod._route(x, p["router"], cfg)
         act = jax.nn.silu
         want = jnp.zeros_like(x)

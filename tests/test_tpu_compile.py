@@ -2,10 +2,10 @@
 
 The TPU compiler refuses what the Pallas interpreter accepts: blocks off the
 (8, 128) tiling, too much VMEM, a program larger than HBM. These tests compile
-the three kernels at the published widths of the models that use them, and the
-full-width deepseek-7b decode step, for one chip of a ``v5e:2x2`` topology;
-the decode step's compiled program must also write its donated cache in
-place.
+the kernels at the published widths of the models that use them, and the
+full-width deepseek-7b and deepseek-v2-lite decode steps, for one chip of a
+``v5e:2x2`` topology; each decode step's compiled program must also write
+its donated cache in place.
 Nothing runs; only the compiler is exercised.
 
 The topology is described inside a fixture (never at import): only one
@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.moe_gmm import gmm_pallas
+from repro.kernels.moe_gmm import expert_ffn_pallas, gmm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.models.model_zoo import build_model
 from repro.runtime.serve import ServeOptions, abstract_cache, build_decode_step
@@ -132,5 +132,64 @@ def test_deepseek_7b_decode_writes_its_donated_cache_in_place(one_chip):
         rf"= bf16\[(\d+,)?{B},{T},{cfg.n_kv_heads},{cfg.head_dim_}\]")
     copies = [line.strip() for line in compiled.as_text().splitlines()
               if cache_shaped.search(line)
+              and (line.lstrip().startswith("%copy") or " copy(" in line)]
+    assert not copies, copies[:3]
+
+
+def test_flash_attention_compiles_with_deepseek_v2_lite_latent_widths(one_chip):
+    """q/k at nope + rope = 192, v at 128, as the latent attention's
+    prefill runs the kernel."""
+    cfg = get_config("deepseek-v2-lite")
+    qk = _on(one_chip, (2, 2048, cfg.n_heads,
+                        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    v = _on(one_chip, (2, 2048, cfg.n_heads, cfg.v_head_dim))
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention_pallas(q, k, v, causal=True)
+    ).lower(qk, qk, v).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block_m, tokens", [(16, 8), (128, 8192)])
+def test_expert_ffn_compiles_at_deepseek_v2_lite_expert_widths(
+        one_chip, block_m, tokens):
+    """The grouped expert kernel over a decode step's and a prefill chunk's
+    routed rows, the 8 held experts' whole weights in VMEM blocks."""
+    cfg = get_config("deepseek-v2-lite")
+    m = cfg.moe
+    n, d, f = m.held, cfg.d_model, m.d_ff_expert
+    M = (-(-tokens * min(m.top_k, n) // block_m) + n) * block_m
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda *a: expert_ffn_pallas(*a, block_m=block_m)
+    ).lower(_on(one_chip, (M, d)), _on(one_chip, (n, d, f)),
+            _on(one_chip, (n, d, f)), _on(one_chip, (n, f, d)),
+            _on(one_chip, (M // block_m,), i32),
+            _on(one_chip, (1,), i32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_deepseek_v2_lite_decode_reads_its_latent_cache_in_place(one_chip):
+    """Published widths, 8 of 64 experts held, batch 8, a 1024-position
+    latent cache (31,104 bytes a token), donated: no cache-sized temporary
+    and no copy of a cache-shaped buffer."""
+    cfg = get_config("deepseek-v2-lite")
+    model = build_model(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(one_chip, a.shape, a.dtype), tree)
+    B, T = 8, 1024
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(abstract_cache(model, B, T))
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache["groups"]))
+    assert cache_bytes == B * T * 31_104
+    compiled = jax.jit(build_decode_step(model, ServeOptions()),
+                       donate_argnums=(1,)).lower(
+        params, cache, _on(one_chip, (B, 1), jnp.int32),
+        _on(one_chip, (), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * cache_bytes
+    # a copy into VMEM (memory space S(1)) is a prefetch, not a second cache
+    cache_shaped = re.compile(rf"= bf16\[(\d+,)?{B},{T},(512|64)\]\{{[^}}]*\}}")
+    copies = [line.strip() for line in compiled.as_text().splitlines()
+              if (m := cache_shaped.search(line)) and "S(1)" not in m.group()
               and (line.lstrip().startswith("%copy") or " copy(" in line)]
     assert not copies, copies[:3]
