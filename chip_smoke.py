@@ -59,15 +59,15 @@ ARCH = "deepseek-7b"
 REQUESTS, BATCH, PROMPT_LEN, NEW_TOKENS = 8, 4, 128, 16
 
 # (b) Weights and activations are bf16 with f32 accumulation, and the two
-# routes round differently: prefill attends through the flash kernel (f32
-# inside), decode contracts the bf16 cache with bf16 probabilities, and each
-# layer re-rounds the residual stream to bf16 (relative step 2**-8), which a
-# deep stack of random layers amplifies. On the CPU path at deepseek-7b's
-# full depth (30 layers, narrower widths) the relative L2 gap measured
-# 0.027-0.044; decoding at the wrong position (index S+1) gave 0.06-0.48 and
+# routes round differently: prefill attends through the flash kernel (bf16
+# products in its own tiles), decode contracts the bf16 cache with bf16
+# probabilities, and each layer re-rounds the residual stream to bf16
+# (relative step 2**-8), which a deep stack of random layers amplifies. On
+# the CPU path at deepseek-7b's full depth (30 layers, narrower widths) the
+# relative L2 gap measured 0.027-0.044; decoding at the wrong position (index S+1) gave 0.06-0.48 and
 # feeding the wrong token 0.7 and more. 0.08 sits between the two.
 DECODE_RTOL = 8e-2
-# (c) The kernel writes bf16 (relative rounding 2**-9) and may feed the
+# (c) The kernel writes bf16 (relative rounding 2**-9) and feeds the
 # softmax probabilities to the MXU in bf16; 2e-2 is the repo's bf16 kernel
 # tolerance (tests/test_kernels.py).
 FLASH_TOL = 2e-2

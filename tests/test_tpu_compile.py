@@ -58,10 +58,15 @@ def _on(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("seq", [128, 2048])
-def test_flash_attention_compiles_at_deepseek_7b_widths(one_chip, seq):
+@pytest.mark.parametrize("batch,seq", [
+    pytest.param(4, 128, id="128"),
+    pytest.param(4, 2048, id="2048"),
+    pytest.param(2, 1280, id="2-1280"),   # ds7b.rag_grade's long prompts
+])
+def test_flash_attention_compiles_at_deepseek_7b_widths(one_chip, batch, seq):
+    """At the tiles flash_plan picks for the shape."""
     cfg = get_config("deepseek-7b")
-    qkv = _on(one_chip, (4, seq, cfg.n_heads, cfg.head_dim_))
+    qkv = _on(one_chip, (batch, seq, cfg.n_heads, cfg.head_dim_))
     compiled = jax.jit(
         lambda q, k, v: flash_attention_pallas(q, k, v, causal=True)
     ).lower(qkv, qkv, qkv).compile()
@@ -136,17 +141,27 @@ def test_deepseek_7b_decode_writes_its_donated_cache_in_place(one_chip):
     assert not copies, copies[:3]
 
 
-def test_flash_attention_compiles_with_deepseek_v2_lite_latent_widths(one_chip):
-    """q/k at nope + rope = 192, v at 128, as the latent attention's
-    prefill runs the kernel."""
+def _latent_flash_compiles(one_chip, batch, seq):
     cfg = get_config("deepseek-v2-lite")
-    qk = _on(one_chip, (2, 2048, cfg.n_heads,
+    qk = _on(one_chip, (batch, seq, cfg.n_heads,
                         cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
-    v = _on(one_chip, (2, 2048, cfg.n_heads, cfg.v_head_dim))
+    v = _on(one_chip, (batch, seq, cfg.n_heads, cfg.v_head_dim))
     compiled = jax.jit(
         lambda q, k, v: flash_attention_pallas(q, k, v, causal=True)
     ).lower(qk, qk, v).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles_with_deepseek_v2_lite_latent_widths(one_chip):
+    """q/k at nope + rope = 192, v at 128, as the latent attention's
+    prefill runs the kernel, at the tiles flash_plan picks."""
+    _latent_flash_compiles(one_chip, 2, 2048)
+
+
+def test_flash_attention_compiles_at_v2lite_ragsynth_long_prompts(one_chip):
+    """B 8, S 8192, the long prompts of v2lite.ragsynth: flash_plan's
+    1024 x 1024 tiles sit at the VMEM budget at q/k 192, v 128."""
+    _latent_flash_compiles(one_chip, 8, 8192)
 
 
 @pytest.mark.parametrize("block_m, tokens", [(16, 8), (128, 8192)])
