@@ -19,7 +19,6 @@ class MoEConfig:
     top_k: int = 0
     num_shared: int = 0             # always-on shared experts
     d_ff_expert: int = 0            # per-expert hidden dim
-    capacity_factor: float = 1.25
     router_aux_coef: float = 0.01   # load-balance auxiliary loss
     first_k_dense: int = 0          # leading dense layers (DeepSeek/Kimi style)
     d_ff_dense: int = 0             # hidden dim of those dense layers

@@ -17,11 +17,7 @@ CONFIG = ModelConfig(
     source="arXiv:2401.06066",
 )
 
-# On one device the expert layer is dropless (every routed row is computed);
-# capacity_factor sizes the expert-parallel (mesh) path's buffers only, and
-# is large here so that path drops nothing at tiny token counts either.
 REDUCED = CONFIG.replace(
     n_layers=3, d_model=64, n_heads=4, n_kv_heads=4, vocab_size=256,
     moe=MoEConfig(num_experts=8, top_k=2, num_shared=1, d_ff_expert=32,
-                  first_k_dense=1, d_ff_dense=128, capacity_factor=64.0,
-                  norm_topk_prob=False))
+                  first_k_dense=1, d_ff_dense=128, norm_topk_prob=False))

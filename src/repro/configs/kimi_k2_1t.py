@@ -21,9 +21,8 @@ CONFIG = ModelConfig(
     source="arXiv:2501.kimi2",
 )
 
-# drop-free capacity on the mesh path too (see deepseek_moe_16b.py note)
 REDUCED = CONFIG.replace(
     n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, vocab_size=256,
     head_dim=16,
     moe=MoEConfig(num_experts=16, top_k=4, num_shared=1, d_ff_expert=32,
-                  first_k_dense=1, d_ff_dense=128, capacity_factor=64.0))
+                  first_k_dense=1, d_ff_dense=128))

@@ -1,15 +1,13 @@
-"""Pallas TPU grouped (per-expert) matmul for MoE expert FFNs.
+"""Pallas TPU grouped expert SwiGLU for the MoE layer's routed rows.
 
-MegaBlocks-style grouped GEMM adapted to the TPU: tokens are pre-gathered into
-a dense (E, C, d) capacity buffer (sort-based dispatch lives in
-``repro.models.moe``), so the kernel is a bank of E independent GEMMs tiled
-for the MXU:
+The rows of each held expert arrive sorted by expert in whole tiles of
+``block_m`` rows (the dropless dispatch lives in ``repro.models.moe``), the
+tiles in use first. One grid step runs one tile through its expert's gate,
+up and down projections, with the hidden kept in VMEM and fp32
+accumulation; consecutive tiles of one expert keep its weight blocks, so
+each expert's weights are read once a call.
 
-grid = (E, C/bc, f/bf, d/bd); the contraction dim is innermost/``arbitrary``
-with an fp32 (bc, bf) VMEM accumulator. Block sizes default to 128 (MXU
-native) and are clamped to the problem size.
-
-Oracle: ``ref.gmm_naive``.
+Oracle: ``ref.expert_ffn_ragged``.
 """
 from __future__ import annotations
 
@@ -19,75 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
-    kd = pl.program_id(3)
-    nd = pl.num_programs(3)
-
-    @pl.when(kd == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    x = x_ref[0]                                # (bc, bd)
-    w = w_ref[0]                                # (bd, bf)
-    acc_scr[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(kd == nd - 1)
-    def _emit():
-        o_ref[0] = acc_scr[...].astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_c", "block_f", "block_d",
-                                    "interpret"))
-def gmm_pallas(x, w, *, block_c=128, block_f=128, block_d=512,
-               interpret=False):
-    """x: (E, C, d), w: (E, d, f) -> (E, C, f)."""
-    E, C, d = x.shape
-    _, _, f = w.shape
-    block_c = min(block_c, C)
-    block_f = min(block_f, f)
-    block_d = min(block_d, d)
-
-    def _pad_to(a, axis, mult):
-        pad = (-a.shape[axis]) % mult
-        if pad:
-            widths = [(0, 0)] * a.ndim
-            widths[axis] = (0, pad)
-            a = jnp.pad(a, widths)
-        return a
-
-    x = _pad_to(_pad_to(x, 1, block_c), 2, block_d)
-    w = _pad_to(_pad_to(w, 1, block_d), 2, block_f)
-    Cp, dp, fp = x.shape[1], x.shape[2], w.shape[2]
-
-    grid = (E, Cp // block_c, fp // block_f, dp // block_d)
-    out = pl.pallas_call(
-        _gmm_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_c, block_d),
-                         lambda e, ic, jf, kd: (e, ic, kd)),
-            pl.BlockSpec((1, block_d, block_f),
-                         lambda e, ic, jf, kd: (e, kd, jf)),
-        ],
-        out_specs=pl.BlockSpec((1, block_c, block_f),
-                               lambda e, ic, jf, kd: (e, ic, jf)),
-        out_shape=jax.ShapeDtypeStruct((E, Cp, fp), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(x, w)
-    return out[:, :C, :f]
-
-
-# ---------------------------------------------------------------------------
-# Grouped expert SwiGLU over routed rows (dropless, one device's experts)
-# ---------------------------------------------------------------------------
 
 
 def _expert_ffn_kernel(tile_group_ref, active_ref, x_ref, wg_ref, wu_ref,

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_pallas
-from .moe_gmm import expert_ffn_pallas, gmm_pallas
+from .moe_gmm import expert_ffn_pallas
 from .ssd_scan import ssd_scan_pallas
 
 
@@ -154,14 +154,6 @@ def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk=128):
 
 def ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
     return ref.ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip)
-
-
-def gmm(x, w):
-    """Grouped per-expert matmul: (E, C, d) @ (E, d, f) -> (E, C, f)."""
-    with jax.named_scope("pk_gmm"):
-        if _use_pallas():
-            return gmm_pallas(x, w)
-        return ref.gmm_naive(x, w)
 
 
 def expert_ffn(x, w_gate, w_up, w_down, tile_group, active_tiles, group_rows,

@@ -234,14 +234,8 @@ def ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
 
 
 # ---------------------------------------------------------------------------
-# Grouped (per-expert) matmul
+# Grouped expert SwiGLU
 # ---------------------------------------------------------------------------
-
-
-def gmm_naive(x, w):
-    """x: (E, C, d), w: (E, d, f) -> (E, C, f) with fp32 accumulation."""
-    return jnp.einsum("ecd,edf->ecf", x, w,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def expert_ffn_ragged(x, w_gate, w_up, w_down, group_rows):

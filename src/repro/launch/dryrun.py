@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..configs.base import SHAPE_CELLS, cell_applicable
 from ..configs.registry import ARCH_IDS, get_config
+from ..models import moe
 from ..models.model_zoo import build_model
 from ..runtime import sharding as shd
 from ..runtime import serve as serve_rt
@@ -249,14 +250,15 @@ def kernel_io_per_device(cfg, cell, n_dev: int) -> float:
 
     def moe_io():
         m = cfg.moe
-        # dispatch buffer in/out of the 3 grouped matmuls + expert weights
-        # streamed once per step (the dominant decode term for big MoE)
-        cap = max(8, int(B * (1 if cell.kind == "decode" else S)
-                         * m.top_k * m.capacity_factor / m.num_experts) + 1)
-        buf = m.num_experts * cap * cfg.d_model * 2.0
-        hid = m.num_experts * cap * m.d_ff_expert * 2.0
-        weights = m.num_experts * 3 * cfg.d_model * m.d_ff_expert * 2.0
-        return (4 * buf + 3 * hid + weights) * train_f
+        # the grouped kernel reads its dropless dispatch buffer (T k rows in
+        # whole tiles) and writes as many rows, the hidden staying in VMEM,
+        # and streams each held expert's weights once a step (the dominant
+        # decode term for big MoE)
+        T = B * (1 if cell.kind == "decode" else S)
+        rows = moe.dispatch_rows(T, m.top_k, m.held, moe.row_tile(T, cfg))
+        buf = rows * cfg.d_model * 2.0
+        weights = m.held * 3 * cfg.d_model * m.d_ff_expert * 2.0
+        return (2 * buf + weights) * train_f
 
     groups = list(layer_plan(cfg))
     if cfg.family == "encdec":
@@ -316,9 +318,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, rules=None,
         cell0 = SHAPE_CELLS[shape]
         if cell0.kind != "train":
             rules = dict(shd.SERVING_RULES, **(rules or {}))
-            opts_over = dict(opts_over or {}, expert_tp=True)
-            if cell0.kind == "decode":      # §Perf B2
-                opts_over["moe_capacity_cap"] = 4
     cfg = get_config(arch)
     cell = SHAPE_CELLS[shape]
     model = build_model(cfg)

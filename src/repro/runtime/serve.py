@@ -15,28 +15,15 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.model_zoo import Model
-from ..models.moe import DistContext, LOCAL
+from ..models.moe import make_dist
 from . import sharding as shd
 from . import tracing
 
 
 @dataclass(frozen=True)
 class ServeOptions:
-    kv_dtype: str = "bfloat16"
     temperature: float = 0.0      # 0 = greedy
-    fsdp_experts: bool = False    # serving default: keep experts TP-only
-    expert_tp: bool = False       # 2D expert sharding (SERVING_RULES, §Perf)
-    moe_capacity_cap: int = 0     # decode capacity cap (§Perf B2)
     scan_unroll: int = 1
-
-
-def make_dist(mesh, opts: ServeOptions) -> DistContext:
-    if mesh is None:
-        return LOCAL
-    return DistContext(mesh=mesh, data_axes=shd.batch_axes(mesh),
-                       model_axis="model", fsdp_experts=opts.fsdp_experts,
-                       ep=True, expert_tp=opts.expert_tp,
-                       capacity_cap=opts.moe_capacity_cap)
 
 
 def cache_shardings(model: Model, cache_abstract, mesh, rules=None):
@@ -50,7 +37,7 @@ def abstract_cache(model: Model, batch: int, max_len: int, enc_len: int = 0):
 
 
 def build_prefill_step(model: Model, opts: ServeOptions, mesh=None):
-    dist = make_dist(mesh, opts)
+    dist = make_dist(mesh)
 
     def prefill(params, inputs, cache):
         logits, cache, _ = model.apply(params, inputs, mode="prefill",
@@ -62,7 +49,7 @@ def build_prefill_step(model: Model, opts: ServeOptions, mesh=None):
 
 
 def build_decode_step(model: Model, opts: ServeOptions, mesh=None):
-    dist = make_dist(mesh, opts)
+    dist = make_dist(mesh)
 
     def decode(params, cache, tokens, index, key=None):
         """tokens: (B, 1); index: scalar int32 position. -> (next, cache)."""

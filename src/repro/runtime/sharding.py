@@ -53,13 +53,13 @@ DEFAULT_RULES: dict[str, tuple[tuple[str, ...], ...]] = {
     "experts_in": ((),),
 }
 
-# Serving-time rules (§Perf A2/B1/C1): weights are read-only at serve time,
-# so FSDP-sharding their embed dims only forces a re-gather (dense archs) or
-# a giant per-layer weight all-gather (MoE) on every step. Replicating the
-# embed dims leaves dense weights TP-only and — because ``expert_mlp`` is
-# the next candidate for the data axis — gives expert weights the 2D
-# EP(model) x TP(data) layout, turning per-step weight movement into a small
-# activation psum inside the MoE body (see models/moe.py::_moe_ep_body).
+# Serving-time rules (§Perf A2/C1): weights are read-only at serve time, so
+# FSDP-sharding their embed dims only forces a re-gather on every step.
+# Replicating the embed dims leaves dense weights TP-only. Expert weights
+# then store ``expert_mlp`` over the data axis (its next candidate); the MoE
+# layer (models/moe.py::apply_moe) splits experts over the model axis only,
+# so its ``shard_map`` boundary all-gathers that dim of each layer's expert
+# weights on every step.
 SERVING_RULES: dict[str, tuple[tuple[str, ...], ...]] = dict(
     DEFAULT_RULES,
     embed=((),), src_embed=((),), vision_embed=((),))

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.model_zoo import Model
-from ..models.moe import DistContext, LOCAL
+from ..models.moe import make_dist
 from ..optim import adamw
 from ..optim.schedule import warmup_cosine
 from . import sharding as shd
@@ -28,7 +28,6 @@ class TrainOptions:
     opt: adamw.AdamWConfig = adamw.AdamWConfig()
     warmup_steps: int = 100
     total_steps: int = 10_000
-    fsdp_experts: bool = True
     scan_unroll: int = 1                 # big value = unroll layer scans
 
 
@@ -38,14 +37,6 @@ def cross_entropy(logits, labels):
     lse = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(lse - gold)
-
-
-def make_dist(mesh, opts: TrainOptions) -> DistContext:
-    if mesh is None:
-        return LOCAL
-    return DistContext(mesh=mesh, data_axes=shd.batch_axes(mesh),
-                       model_axis="model", fsdp_experts=opts.fsdp_experts,
-                       ep=True)
 
 
 def init_train_state(model: Model, key, opts: TrainOptions):
@@ -90,7 +81,7 @@ def batch_shardings(batch_abstract, mesh):
 
 def build_train_step(model: Model, opts: TrainOptions, mesh=None,
                      rules=None) -> Callable:
-    dist = make_dist(mesh, opts)
+    dist = make_dist(mesh)
 
     def loss_fn(params, batch):
         inputs = {k: v for k, v in batch.items() if k != "labels"}

@@ -9,7 +9,6 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import (VMEM_BUDGET, flash_attention_pallas,
                                           flash_blocks, flash_plan,
                                           vmem_bytes)
-from repro.kernels.moe_gmm import gmm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
@@ -207,23 +206,6 @@ class TestSSDScan:
                                    atol=1e-4, rtol=1e-4)
         np.testing.assert_allclose(np.asarray(state), np.asarray(st_scan),
                                    atol=1e-4, rtol=1e-4)
-
-
-class TestGMM:
-    @pytest.mark.parametrize("E,C,d,f", [
-        (2, 16, 32, 64), (8, 64, 128, 64), (4, 8, 256, 128),
-    ])
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_vs_naive(self, E, C, d, f, dtype):
-        k1, k2 = jax.random.split(jax.random.PRNGKey(0))
-        x = jax.random.normal(k1, (E, C, d), dtype)
-        w = jax.random.normal(k2, (E, d, f), dtype)
-        got = gmm_pallas(x, w, interpret=True)
-        want = ref.gmm_naive(x, w)
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32),
-                                   atol=TOL[dtype] * d ** 0.5,
-                                   rtol=TOL[dtype])
 
 
 class TestOpsDispatch:

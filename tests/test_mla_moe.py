@@ -93,31 +93,45 @@ def test_flash_attention_with_a_narrower_v():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_expert_ffn_kernel_matches_its_twin():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rows, d, f", [
+    pytest.param((20, 0, 9), 64, 32, id="3g-64-32"),
+    pytest.param((16, 11), 32, 64, id="2g-32-64"),
+    pytest.param((64, 59, 54, 49, 44, 39, 34, 0), 128, 64, id="8g-128-64"),
+    pytest.param((8, 3, 6, 0), 256, 128, id="4g-256-128"),
+])
+def test_expert_ffn_kernel_matches_its_twin(rows, d, f, dtype):
     """The grouped kernel in interpret mode against the ragged jnp twin:
-    experts of 20, 0 and 9 rows in whole 16-row tiles (one expert empty),
-    then unused tiles, whose rows the kernel leaves unwritten and nothing
-    reads. Both float32."""
-    G, d, f, bm = 3, 64, 32, 16
+    experts of uneven row counts (some empty) in whole 16-row tiles, then
+    three unused tiles, whose rows the kernel leaves unwritten and nothing
+    reads. Both cast the hidden and the output to ``dtype`` and accumulate
+    in float32, so they agree to float32 rounding, or to a bfloat16 step
+    where a rounding of the hidden falls the other way."""
+    G, bm = len(rows), 16
     ks = jax.random.split(jax.random.PRNGKey(8), 4)
-    wg, wu = (jax.random.normal(k, (G, d, f)) / 8 for k in ks[:2])
-    wd = jax.random.normal(ks[2], (G, f, d)) / 6
-    rows = np.array([20, 0, 9])
-    tiles = -(-rows // bm)                                # 2, 0, 1
+    wg, wu = (jax.random.normal(k, (G, d, f)) / d ** 0.5 for k in ks[:2])
+    wd = jax.random.normal(ks[2], (G, f, d)) / f ** 0.5
+    rows = np.array(rows)
+    tiles = -(-rows // bm)
     group_rows = jnp.asarray(tiles * bm, jnp.int32)
-    M = 6 * bm
-    x = jax.random.normal(ks[3], (M, d))
-    live = np.zeros(M, bool)
-    live[:20], live[32:41] = True, True                   # padding rows: 0
-    x = jnp.where(live[:, None], x, 0.0)
-    tile_group = jnp.asarray([0, 0, 2, 2, 2, 2], jnp.int32)
+    n = int(tiles.sum()) * bm
+    M = n + 3 * bm
+    live = np.zeros(M, bool)                              # padding rows: 0
+    for start, r in zip(np.cumsum(tiles * bm) - tiles * bm, rows):
+        live[start:start + r] = True
+    x = jnp.where(live[:, None], jax.random.normal(ks[3], (M, d)), 0.0)
+    tile_group = jnp.asarray(np.minimum(
+        np.searchsorted(np.cumsum(tiles), np.arange(M // bm), side="right"),
+        G - 1), jnp.int32)
+    x, wg, wu, wd = (a.astype(dtype) for a in (x, wg, wu, wd))
     got = expert_ffn_pallas(x, wg, wu, wd, tile_group,
                             jnp.asarray([tiles.sum()], jnp.int32),
                             block_m=bm, interpret=True)
     want = ref.expert_ffn_ragged(x, wg, wu, wd, group_rows)
-    n = int(tiles.sum()) * bm
-    np.testing.assert_allclose(np.asarray(got[:n]), np.asarray(want[:n]),
-                               rtol=1e-5, atol=1e-5)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got[:n], np.float32),
+                               np.asarray(want[:n], np.float32),
+                               rtol=tol, atol=tol)
 
 
 def _uncut(x, p, cfg):

@@ -21,9 +21,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.moe_gmm import expert_ffn_pallas, gmm_pallas
+from repro.kernels.moe_gmm import expert_ffn_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.models.model_zoo import build_model
+from repro.models.moe import dispatch_rows
 from repro.runtime.serve import ServeOptions, abstract_cache, build_decode_step
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -70,14 +71,6 @@ def test_flash_attention_compiles_at_deepseek_7b_widths(one_chip, batch, seq):
     compiled = jax.jit(
         lambda q, k, v: flash_attention_pallas(q, k, v, causal=True)
     ).lower(qkv, qkv, qkv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_gmm_compiles_at_deepseek_moe_16b_expert_widths(one_chip):
-    cfg = get_config("deepseek-moe-16b")
-    E, d, f = cfg.moe.num_experts, cfg.d_model, cfg.moe.d_ff_expert
-    compiled = jax.jit(gmm_pallas).lower(
-        _on(one_chip, (E, 128, d)), _on(one_chip, (E, d, f))).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -164,15 +157,11 @@ def test_flash_attention_compiles_at_v2lite_ragsynth_long_prompts(one_chip):
     _latent_flash_compiles(one_chip, 8, 8192)
 
 
-@pytest.mark.parametrize("block_m, tokens", [(16, 8), (128, 8192)])
-def test_expert_ffn_compiles_at_deepseek_v2_lite_expert_widths(
-        one_chip, block_m, tokens):
-    """The grouped expert kernel over a decode step's and a prefill chunk's
-    routed rows, the 8 held experts' whole weights in VMEM blocks."""
-    cfg = get_config("deepseek-v2-lite")
+def _expert_ffn_compiles(one_chip, arch, block_m, tokens):
+    cfg = get_config(arch)
     m = cfg.moe
     n, d, f = m.held, cfg.d_model, m.d_ff_expert
-    M = (-(-tokens * min(m.top_k, n) // block_m) + n) * block_m
+    M = dispatch_rows(tokens, m.top_k, n, block_m)
     i32 = jnp.int32
     compiled = jax.jit(
         lambda *a: expert_ffn_pallas(*a, block_m=block_m)
@@ -181,6 +170,21 @@ def test_expert_ffn_compiles_at_deepseek_v2_lite_expert_widths(
             _on(one_chip, (M // block_m,), i32),
             _on(one_chip, (1,), i32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block_m, tokens", [(16, 8), (128, 8192)])
+def test_expert_ffn_compiles_at_deepseek_v2_lite_expert_widths(
+        one_chip, block_m, tokens):
+    """The grouped expert kernel over a decode step's and a prefill chunk's
+    routed rows, the 8 held experts' whole weights in VMEM blocks."""
+    _expert_ffn_compiles(one_chip, "deepseek-v2-lite", block_m, tokens)
+
+
+@pytest.mark.parametrize("block_m, tokens", [(16, 8), (128, 8192)])
+def test_expert_ffn_compiles_at_deepseek_moe_16b_expert_widths(
+        one_chip, block_m, tokens):
+    """As above, with all 64 experts of deepseek-moe-16b held."""
+    _expert_ffn_compiles(one_chip, "deepseek-moe-16b", block_m, tokens)
 
 
 def test_deepseek_v2_lite_decode_reads_its_latent_cache_in_place(one_chip):
