@@ -156,13 +156,18 @@ def ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
     return ref.ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip)
 
 
-def expert_ffn(x, w_gate, w_up, w_down, tile_group, active_tiles, group_rows,
-               *, block_m):
+def expert_ffn(x, w_gate, w_up, w_down, layer, tile_group, active_tiles,
+               group_rows, *, block_m):
     """SwiGLU of rows sorted by expert in whole tiles of ``block_m`` rows
-    (``moe_gmm.expert_ffn_pallas``); rows of unused tiles are undefined.
-    ``group_rows`` (G,) gives each expert's rows for the jnp twin."""
+    (``moe_gmm.expert_ffn_pallas``), under the experts of ``layer`` (scalar
+    int32) in the weight stacks (L, G, ...); rows of unused tiles are
+    undefined. ``group_rows`` (G,) gives each expert's rows for the jnp
+    twin."""
     with jax.named_scope("pk_expert_ffn"):
         if _use_pallas():
-            return expert_ffn_pallas(x, w_gate, w_up, w_down, tile_group,
-                                     active_tiles, block_m=block_m)
-        return ref.expert_ffn_ragged(x, w_gate, w_up, w_down, group_rows)
+            return expert_ffn_pallas(
+                x, w_gate, w_up, w_down,
+                jnp.reshape(layer, (1,)).astype(jnp.int32), tile_group,
+                active_tiles, block_m=block_m)
+        return ref.expert_ffn_ragged(x, w_gate, w_up, w_down, layer,
+                                     group_rows)

@@ -238,12 +238,13 @@ def ssd_decode_step(state, x_t, dt_t, a_log, b_t, c_t, d_skip):
 # ---------------------------------------------------------------------------
 
 
-def expert_ffn_ragged(x, w_gate, w_up, w_down, group_rows):
-    """SwiGLU of each row under its group's weights: x (M, d) rows sorted
-    by group, ``group_rows`` (G,) rows of each group in order; rows past
-    them give 0. Twin of ``moe_gmm.expert_ffn_pallas``."""
+def expert_ffn_ragged(x, w_gate, w_up, w_down, layer, group_rows):
+    """SwiGLU of each row under its group's weights in ``layer`` of the
+    stacks (L, G, ...): x (M, d) rows sorted by group, ``group_rows`` (G,)
+    rows of each group in order; rows past them give 0. Twin of
+    ``moe_gmm.expert_ffn_pallas``."""
     def mm(a, w):  # fp32 operands: the CPU's ragged dot takes no bf16 -> f32
         return jax.lax.ragged_dot(a.astype(jnp.float32),
-                                  w.astype(jnp.float32), group_rows)
+                                  w[layer].astype(jnp.float32), group_rows)
     h = jax.nn.silu(mm(x, w_gate)) * mm(x, w_up)
     return mm(h.astype(x.dtype), w_down).astype(x.dtype)

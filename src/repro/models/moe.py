@@ -15,6 +15,11 @@ token count (all of a token's k choices could fall here), never from experts
 x tokens, and long inputs go through in chunks of ``CHUNK_TOKENS``. What the
 absent experts add lies on other devices; nothing here stands in for them.
 
+The kernel reads the held experts' weights in place from the stack of every
+layer's (L, held, ...), at a layer index: the layer scan closes over the
+stacks whole and passes its index, so no layer's slice is copied out. One
+layer's weights (held, ...) are a stack of one at layer 0.
+
 On a mesh the same layer runs under ``jax.shard_map``, once per shard: the
 tokens are split over the data axes and replicated over the model axis, and
 the held experts are split over the model axis, so each shard holds a share
@@ -100,6 +105,15 @@ def _route(x2d, router_w, cfg):
 
 
 CHUNK_TOKENS = 8192  # tokens per pass of the one-device expert layer
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")  # stacked per layer, read in place
+
+
+def _stacked(p, layer):
+    """(p, layer) with the expert weights as stacks: one layer's weights
+    (``layer`` None) become a stack of one at layer 0, a free reshape."""
+    if layer is not None:
+        return p, layer
+    return {**p, **{k: p[k][None] for k in EXPERT_WEIGHTS}}, 0
 
 
 def row_tile(n_tokens: int, cfg) -> int:
@@ -150,18 +164,19 @@ def _dispatch_held(topk_idx, n: int, offset, bm: int):
             counts[:n].sum())
 
 
-def _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg, offset):
+def _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg, offset, layer):
     """The held experts' part of the routed result for one chunk of tokens:
-    the experts of ``p``'s weights, from ``offset`` on. Returns (out (T, d),
-    [held assignments, rows computed, held experts with a row]): the kernel
-    reads each such expert's weights once, and no other's."""
+    the experts of ``layer`` in ``p``'s weight stacks, from ``offset`` on.
+    Returns (out (T, d), [held assignments, rows computed, held experts with
+    a row]): the kernel reads each such expert's weights once, and no
+    other's."""
     T, d = x2d.shape
     bm = row_tile(T, cfg)
     slot, row_token, tile_group, active, group_rows, routed = _dispatch_held(
-        topk_idx, p["w_gate"].shape[0], offset, bm)
+        topk_idx, p["w_gate"].shape[1], offset, bm)
     x_pad = jnp.concatenate([x2d, jnp.zeros((1, d), x2d.dtype)], 0)
     y = ops.expert_ffn(x_pad[row_token], p["w_gate"], p["w_up"],
-                       p["w_down"], tile_group, active, group_rows,
+                       p["w_down"], layer, tile_group, active, group_rows,
                        block_m=bm)
     y_pad = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)], 0)
     y_tok = y_pad[slot].reshape(T, topk_idx.shape[1], d)  # elsewhere -> 0
@@ -171,16 +186,20 @@ def _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg, offset):
     return out, jnp.stack([routed, active[0] * bm, hit])
 
 
-def _moe_local(x2d, p, cfg, offset):
+def _moe_local(x2d, p, cfg, offset, layer=None):
     """One device's MoE: route over all experts, then the held experts (from
     ``offset`` on) over their rows, dropless, in chunks of ``CHUNK_TOKENS``
-    tokens. Returns (out, aux, rows): rows = int32 [assignments that fell on
-    the held experts, rows the grouped kernel computed, held experts it read
-    the weights of], summed over the chunks."""
+    tokens. The expert weights are stacks read at ``layer``, or one layer's
+    where ``layer`` is None. Returns (out, aux, rows): rows = int32
+    [assignments that fell on the held experts, rows the grouped kernel
+    computed, held experts it read the weights of], summed over the
+    chunks."""
     T, d = x2d.shape
+    p, layer = _stacked(p, layer)
     topk_idx, topk_w, aux = _route(x2d, p["router"], cfg)
     if T <= CHUNK_TOKENS:
-        out, rows = _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg, offset)
+        out, rows = _moe_held_chunk(x2d, topk_idx, topk_w, p, cfg, offset,
+                                    layer)
         return out, aux, rows
     n, pad = -(-T // CHUNK_TOKENS), -T % CHUNK_TOKENS
     chunks = lambda a, fill: jnp.pad(
@@ -188,42 +207,47 @@ def _moe_local(x2d, p, cfg, offset):
     ).reshape(n, CHUNK_TOKENS, *a.shape[1:])
     # padding tokens choose an expert no device holds
     out, rows = jax.lax.map(
-        lambda c: _moe_held_chunk(*c, p, cfg, offset),
+        lambda c: _moe_held_chunk(*c, p, cfg, offset, layer),
         (chunks(x2d, 0), chunks(topk_idx, -1), chunks(topk_w, 0)))
     return out.reshape(n * CHUNK_TOKENS, d)[:T], aux, rows.sum(0)
 
 
-def _moe_shard(x2d, router, w_gate, w_up, w_down, *, cfg, dist):
+def _moe_shard(x2d, router, w_gate, w_up, w_down, layer, *, cfg, dist):
     """``shard_map`` body: one data shard's tokens through this model
-    shard's experts, the parts added up over the model axis."""
+    shard's experts of ``layer``, the parts added up over the model axis."""
     ax = dist.model_axis
-    offset = cfg.moe.experts_offset + jax.lax.axis_index(ax) * w_gate.shape[0]
+    offset = cfg.moe.experts_offset + jax.lax.axis_index(ax) * w_gate.shape[1]
     p = {"router": router, "w_gate": w_gate, "w_up": w_up, "w_down": w_down}
-    out, aux, rows = _moe_local(x2d, p, cfg, offset)
+    out, aux, rows = _moe_local(x2d, p, cfg, offset, layer)
     return (jax.lax.psum(out, ax), jax.lax.pmean(aux, dist.data_axes),
             jax.lax.psum(rows, dist.data_axes + (ax,)))
 
 
-def apply_moe(p, x, *, cfg, dist: DistContext = LOCAL):
+def apply_moe(p, x, *, cfg, dist: DistContext = LOCAL, layer=None):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar, rows int32[3]):
     rows counts the assignments that fell on the held experts, the rows the
     grouped kernel computed and the experts whose weights it read, summed
-    over the shards on a mesh. On a mesh the expert weights are split over
-    its model axis at the ``shard_map`` boundary, and any other sharding of
-    theirs is gathered there."""
+    over the shards on a mesh. ``p``'s expert weights are every layer's
+    stacks (L, held, ...) read at ``layer`` (scalar int32), or one layer's
+    (held, ...) where ``layer`` is None. On a mesh the expert weights are
+    split over its model axis at the ``shard_map`` boundary, and any other
+    sharding of theirs is gathered there."""
     B, S, d = x.shape
     x2d = x.reshape(B * S, d)
     m = cfg.moe
+    p, layer = _stacked(p, layer)
     if dist.mesh is None:
-        out, aux, rows = _moe_local(x2d, p, cfg, m.experts_offset)
+        out, aux, rows = _moe_local(x2d, p, cfg, m.experts_offset, layer)
     else:
-        experts = P(dist.model_axis)
+        experts = P(None, dist.model_axis)
         # check_vma off: the Pallas kernel's output shape names no mesh axes
         out, aux, rows = jax.shard_map(
             partial(_moe_shard, cfg=cfg, dist=dist), mesh=dist.mesh,
-            in_specs=(P(dist.data_axes), P(), experts, experts, experts),
+            in_specs=(P(dist.data_axes), P(), experts, experts, experts,
+                      P()),
             out_specs=(P(dist.data_axes), P(), P()), check_vma=False,
-        )(x2d, p["router"], p["w_gate"], p["w_up"], p["w_down"])
+        )(x2d, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+          jnp.asarray(layer, jnp.int32))
     if m.num_shared:
         from .ffn import apply_ffn
         out = out + apply_ffn(p["shared"], x, cfg=cfg).reshape(B * S, d)

@@ -15,7 +15,9 @@ rows into the stacked cache at ``cache_index`` and replaces the rest whole;
 the serving steps donate the cache, so the write happens in place. A model
 with expert layers also carries ``moe_rows`` in its cache: its experts'
 routed rows, computed rows and weight reads, summed over layers and steps,
-one row for prefill and one for decode. Prefill
+one row for prefill and one for decode. The held experts' weights are not
+scanned: the scan closes over their stacks whole and hands the expert layer
+its layer index, so the grouped kernel reads them in place. Prefill
 evaluates the head at the last position only.
 """
 from __future__ import annotations
@@ -30,7 +32,7 @@ from .attention import (apply_attention, attention_specs, compute_cross_kv,
 from .common import (ParamSpec, apply_norm, norm_spec, softcap)
 from .ffn import apply_ffn, ffn_specs
 from .mla import apply_mla, mla_specs
-from .moe import DistContext, LOCAL, apply_moe, moe_specs
+from .moe import EXPERT_WEIGHTS, DistContext, LOCAL, apply_moe, moe_specs
 from .ssm import (apply_ssm, apply_ssm_decode, init_ssm_state,
                   ssm_specs)
 
@@ -225,10 +227,12 @@ def _has_moe(cfg) -> bool:
 
 
 def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
-                 cross_states, shared_params, positions):
+                 cross_states, shared_params, positions, layer=None):
     """One residual block. Returns (x, cache_out|None, aux, rows|None):
     cache_out is what ``_write_cache`` puts into this block's cache, rows an
-    expert layer's [routed rows, computed rows, experts read] counts."""
+    expert layer's [routed rows, computed rows, experts read] counts. An
+    expert layer's weights are every layer's stacks read at ``layer``, or
+    one layer's where ``layer`` is None."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = rows = None
 
@@ -263,7 +267,8 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         x = x + maybe_post(apply_ffn(bp["ffn"], h, cfg=cfg), bp)
     elif b.kind == "moe":
         h = apply_norm(bp["norm"], x, cfg)
-        out, aux, rows = apply_moe(bp["moe"], h, cfg=cfg, dist=dist)
+        out, aux, rows = apply_moe(bp["moe"], h, cfg=cfg, dist=dist,
+                                   layer=layer)
         x = x + maybe_post(out, bp)
     elif b.kind == "ssm":
         h = apply_norm(bp["norm"], x, cfg)
@@ -310,19 +315,35 @@ def _apply_group(gp, x, gd: GroupDesc, *, cfg, dist, mode, cache, cache_index,
                  unroll: int = 1):
     """Scan the group body over its ``repeat`` stacked layers. Returns (x,
     aux, emitted cache, expert rows summed over the layers, or None where
-    the group has no expert layer)."""
+    the group has no expert layer).
+
+    An expert layer's held experts' weights stay out of the scanned
+    slices: the body closes over their stacks whole and gets the layer's
+    index, so the grouped kernel, a custom call that XLA cannot fuse a
+    slice into, reads them in place."""
+    experts = {f"b{j}": {w: gp[f"b{j}"]["moe"][w] for w in EXPERT_WEIGHTS}
+               for j, b in enumerate(gd.blocks) if b.kind == "moe"}
+    if experts:
+        gp = {k: {**v, "moe": {w: a for w, a in v["moe"].items()
+                               if w not in EXPERT_WEIGHTS}}
+              if k in experts else v for k, v in gp.items()}
+    layers = jnp.arange(gd.repeat, dtype=jnp.int32) if experts else None
 
     def body(carry, xs):
         h, aux, rows = carry
-        bp_all, bc_all = xs
+        bp_all, bc_all, layer = xs
         new_caches = {}
         for j, b in enumerate(gd.blocks):
             key = f"b{j}"
             bc = None if bc_all is None else bc_all.get(key)
+            bp = bp_all[key]
+            if key in experts:
+                bp = {**bp, "moe": {**bp["moe"], **experts[key]}}
             h, nc, aux_j, rows_j = _apply_block(
-                bp_all[key], h, b, cfg=cfg, dist=dist, mode=mode, cache=bc,
+                bp, h, b, cfg=cfg, dist=dist, mode=mode, cache=bc,
                 cache_index=cache_index, cross_states=cross_states,
-                shared_params=shared_params, positions=positions)
+                shared_params=shared_params, positions=positions,
+                layer=layer)
             if nc is not None:
                 new_caches[key] = nc
             aux = aux + aux_j
@@ -332,10 +353,9 @@ def _apply_group(gp, x, gd: GroupDesc, *, cfg, dist, mode, cache, cache_index,
 
     body = _maybe_remat(body, remat_policy, mode)
 
-    rows0 = (jnp.zeros((3,), jnp.int32)
-             if any(b.kind == "moe" for b in gd.blocks) else None)
+    rows0 = jnp.zeros((3,), jnp.int32) if experts else None
     (x, aux, rows), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32), rows0), (gp, cache),
+        body, (x, jnp.zeros((), jnp.float32), rows0), (gp, cache, layers),
         unroll=min(unroll, gd.repeat) or 1)
     return x, aux, new_cache, rows
 

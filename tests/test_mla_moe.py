@@ -17,7 +17,8 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gmm import expert_ffn_pallas
 from repro.models import mla, moe
-from repro.models.common import init_params, yarn_inv_freq
+from repro.models import transformer
+from repro.models.common import apply_norm, init_params, yarn_inv_freq
 from repro.models.ffn import apply_ffn
 from repro.models.model_zoo import build_model
 from repro.runtime import tracing
@@ -94,23 +95,30 @@ def test_flash_attention_with_a_narrower_v():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("rows, d, f", [
-    pytest.param((20, 0, 9), 64, 32, id="3g-64-32"),
-    pytest.param((16, 11), 32, 64, id="2g-32-64"),
-    pytest.param((64, 59, 54, 49, 44, 39, 34, 0), 128, 64, id="8g-128-64"),
-    pytest.param((8, 3, 6, 0), 256, 128, id="4g-256-128"),
+@pytest.mark.parametrize("rows, d, f, layers, layer", [
+    pytest.param((20, 0, 9), 64, 32, 1, 0, id="3g-64-32"),
+    pytest.param((16, 11), 32, 64, 1, 0, id="2g-32-64"),
+    pytest.param((64, 59, 54, 49, 44, 39, 34, 0), 128, 64, 1, 0,
+                 id="8g-128-64"),
+    pytest.param((8, 3, 6, 0), 256, 128, 1, 0, id="4g-256-128"),
+    pytest.param((20, 0, 9), 64, 32, 3, 0, id="3g-64-32-layer0of3"),
+    pytest.param((20, 0, 9), 64, 32, 3, 1, id="3g-64-32-layer1of3"),
+    pytest.param((20, 0, 9), 64, 32, 3, 2, id="3g-64-32-layer2of3"),
 ])
-def test_expert_ffn_kernel_matches_its_twin(rows, d, f, dtype):
+def test_expert_ffn_kernel_matches_its_twin(rows, d, f, layers, layer, dtype):
     """The grouped kernel in interpret mode against the ragged jnp twin:
     experts of uneven row counts (some empty) in whole 16-row tiles, then
     three unused tiles, whose rows the kernel leaves unwritten and nothing
-    reads. Both cast the hidden and the output to ``dtype`` and accumulate
+    reads. The kernel reads ``layer`` of a stack of ``layers`` layers whose
+    weights all differ; the twin gets that layer's slice alone, as a stack
+    of one. Both cast the hidden and the output to ``dtype`` and accumulate
     in float32, so they agree to float32 rounding, or to a bfloat16 step
     where a rounding of the hidden falls the other way."""
     G, bm = len(rows), 16
     ks = jax.random.split(jax.random.PRNGKey(8), 4)
-    wg, wu = (jax.random.normal(k, (G, d, f)) / d ** 0.5 for k in ks[:2])
-    wd = jax.random.normal(ks[2], (G, f, d)) / f ** 0.5
+    wg, wu = (jax.random.normal(k, (layers, G, d, f)) / d ** 0.5
+              for k in ks[:2])
+    wd = jax.random.normal(ks[2], (layers, G, f, d)) / f ** 0.5
     rows = np.array(rows)
     tiles = -(-rows // bm)
     group_rows = jnp.asarray(tiles * bm, jnp.int32)
@@ -124,10 +132,11 @@ def test_expert_ffn_kernel_matches_its_twin(rows, d, f, dtype):
         np.searchsorted(np.cumsum(tiles), np.arange(M // bm), side="right"),
         G - 1), jnp.int32)
     x, wg, wu, wd = (a.astype(dtype) for a in (x, wg, wu, wd))
-    got = expert_ffn_pallas(x, wg, wu, wd, tile_group,
-                            jnp.asarray([tiles.sum()], jnp.int32),
+    got = expert_ffn_pallas(x, wg, wu, wd, jnp.asarray([layer], jnp.int32),
+                            tile_group, jnp.asarray([tiles.sum()], jnp.int32),
                             block_m=bm, interpret=True)
-    want = ref.expert_ffn_ragged(x, wg, wu, wd, group_rows)
+    want = ref.expert_ffn_ragged(x, wg[layer:layer + 1], wu[layer:layer + 1],
+                                 wd[layer:layer + 1], 0, group_rows)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got[:n], np.float32),
                                np.asarray(want[:n], np.float32),
@@ -217,6 +226,77 @@ def test_routing_elsewhere_computes_nothing():
     shared = apply_ffn(p["shared"], x, cfg=CFG)
     np.testing.assert_allclose(np.asarray(out), np.asarray(shared))
     assert [int(r) for r in rows] == [0, 0, 0]
+
+
+def _layer_by_layer(params, tokens, cfg, cache, cache_index, mode):
+    """``forward`` as a Python loop over the layers, each handed its own
+    slice of every stacked parameter and cache leaf (an expert layer gets
+    one layer's weights). Returns (logits of the last position, each
+    attention block's emitted rows by (group, block, layer), the expert
+    row counters summed)."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    positions = cache_index + jnp.arange(tokens.shape[1])[None, :]
+    emitted, rows = {}, jnp.zeros((3,), jnp.int32)
+    for i, gd in enumerate(transformer.layer_plan(cfg)):
+        gp, gc = params["groups"][f"g{i}"], cache["groups"][f"g{i}"]
+        for layer in range(gd.repeat):
+            for j, b in enumerate(gd.blocks):
+                at = lambda tree: jax.tree.map(lambda a: a[layer], tree)
+                bc = at(gc[f"b{j}"]) if f"b{j}" in gc else None
+                x, nc, _, r = transformer._apply_block(
+                    at(gp[f"b{j}"]), x, b, cfg=cfg, dist=moe.LOCAL,
+                    mode=mode, cache=bc, cache_index=cache_index,
+                    cross_states=None, shared_params=None,
+                    positions=positions)
+                if nc is not None:
+                    emitted[(i, j, layer)] = nc
+                if r is not None:
+                    rows = rows + r
+    x = apply_norm(params["final_norm"], x[:, -1:], cfg)
+    return x @ params["lm_head"], emitted, rows
+
+
+def test_scan_reads_each_layers_experts_like_a_loop_over_layers():
+    """A float32 model with three expert layers whose weights all differ:
+    prefill then one decode step through ``forward``, whose scan hands the
+    expert layer the whole stacks and the layer's index, against a loop
+    over the layers handing each its own slice. Logits, the latent cache
+    rows and the expert row counters agree to float32 rounding: 1e-4
+    relative, as the other expert-layer tests."""
+    cfg = CFG.replace(n_layers=4, param_dtype="float32",
+                      activ_dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(11))
+    w = params["groups"]["g1"]["b1"]["moe"]["w_gate"]
+    assert w.shape[0] == 3 and not np.allclose(w[0], w[1])
+    B, S = 2, 24
+    tokens = jax.random.randint(jax.random.PRNGKey(12), (B, S + 1), 0,
+                                cfg.vocab_size, dtype=jnp.int32)
+    cache = model.init_cache(B, S + 4)
+    logits, cache, _ = model.apply(params, {"tokens": tokens[:, :S]},
+                                   mode="prefill", cache=cache,
+                                   cache_index=0)
+    want, emitted, rows = _layer_by_layer(params, tokens[:, :S], cfg,
+                                          model.init_cache(B, S + 4), 0,
+                                          "prefill")
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    for (i, j, layer), nc in emitted.items():
+        for k, v in nc.items():
+            got = cache["groups"][f"g{i}"][f"b{j}"][k][layer, :, :S]
+            np.testing.assert_allclose(np.asarray(got), np.asarray(v),
+                                       rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(cache["moe_rows"][0]),
+                                  np.asarray(rows))
+    assert int(rows[0]) > 0
+    logits, new, _ = model.apply(params, {"tokens": tokens[:, S:]},
+                                 mode="decode", cache=cache, cache_index=S)
+    want, _, rows = _layer_by_layer(params, tokens[:, S:], cfg, cache, S,
+                                    "decode")
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(new["moe_rows"][1]),
+                                  np.asarray(rows))
 
 
 def test_latent_cache_bytes_at_published_widths():

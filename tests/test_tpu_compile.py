@@ -5,7 +5,8 @@ The TPU compiler refuses what the Pallas interpreter accepts: blocks off the
 the kernels at the published widths of the models that use them, and the
 full-width deepseek-7b and deepseek-v2-lite decode steps, for one chip of a
 ``v5e:2x2`` topology; each decode step's compiled program must also write
-its donated cache in place.
+its donated cache in place, and deepseek-v2-lite's must read its experts'
+weights in place.
 Nothing runs; only the compiler is exercised.
 
 The topology is described inside a fixture (never at import): only one
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.kernels.ops as ops
 from repro.configs.registry import get_config
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gmm import expert_ffn_pallas
@@ -157,7 +159,9 @@ def test_flash_attention_compiles_at_v2lite_ragsynth_long_prompts(one_chip):
     _latent_flash_compiles(one_chip, 8, 8192)
 
 
-def _expert_ffn_compiles(one_chip, arch, block_m, tokens):
+def _expert_ffn_compiles(one_chip, arch, block_m, tokens, L):
+    """The kernel over ``tokens`` tokens' routed rows, the held experts of
+    one layer read from a stack of ``L`` layers."""
     cfg = get_config(arch)
     m = cfg.moe
     n, d, f = m.held, cfg.d_model, m.d_ff_expert
@@ -165,9 +169,9 @@ def _expert_ffn_compiles(one_chip, arch, block_m, tokens):
     i32 = jnp.int32
     compiled = jax.jit(
         lambda *a: expert_ffn_pallas(*a, block_m=block_m)
-    ).lower(_on(one_chip, (M, d)), _on(one_chip, (n, d, f)),
-            _on(one_chip, (n, d, f)), _on(one_chip, (n, f, d)),
-            _on(one_chip, (M // block_m,), i32),
+    ).lower(_on(one_chip, (M, d)), _on(one_chip, (L, n, d, f)),
+            _on(one_chip, (L, n, d, f)), _on(one_chip, (L, n, f, d)),
+            _on(one_chip, (1,), i32), _on(one_chip, (M // block_m,), i32),
             _on(one_chip, (1,), i32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -176,15 +180,17 @@ def _expert_ffn_compiles(one_chip, arch, block_m, tokens):
 def test_expert_ffn_compiles_at_deepseek_v2_lite_expert_widths(
         one_chip, block_m, tokens):
     """The grouped expert kernel over a decode step's and a prefill chunk's
-    routed rows, the 8 held experts' whole weights in VMEM blocks."""
-    _expert_ffn_compiles(one_chip, "deepseek-v2-lite", block_m, tokens)
+    routed rows, the 8 held experts' whole weights in VMEM blocks, read
+    from the stacks of all 26 expert layers at a layer index."""
+    _expert_ffn_compiles(one_chip, "deepseek-v2-lite", block_m, tokens, 26)
 
 
 @pytest.mark.parametrize("block_m, tokens", [(16, 8), (128, 8192)])
 def test_expert_ffn_compiles_at_deepseek_moe_16b_expert_widths(
         one_chip, block_m, tokens):
-    """As above, with all 64 experts of deepseek-moe-16b held."""
-    _expert_ffn_compiles(one_chip, "deepseek-moe-16b", block_m, tokens)
+    """As above, with all 64 experts of deepseek-moe-16b held, from a stack
+    of 4 layers: its 27 expert layers (29.9 GB) exceed one chip's HBM."""
+    _expert_ffn_compiles(one_chip, "deepseek-moe-16b", block_m, tokens, 4)
 
 
 def test_deepseek_v2_lite_decode_reads_its_latent_cache_in_place(one_chip):
@@ -212,3 +218,29 @@ def test_deepseek_v2_lite_decode_reads_its_latent_cache_in_place(one_chip):
               if (m := cache_shaped.search(line)) and "S(1)" not in m.group()
               and (line.lstrip().startswith("%copy") or " copy(" in line)]
     assert not copies, copies[:3]
+
+
+def test_deepseek_v2_lite_decode_reads_the_held_experts_in_place(
+        one_chip, monkeypatch):
+    """Published widths, batch 8, with the Pallas kernels the chip runs:
+    the grouped expert kernel reads each layer's 8 held experts from the
+    stacks of all 26 expert layers, so the decode step leaves no buffer in
+    HBM shaped like one layer's held share (8 x 2048 x 1408, 46 MB a
+    weight) for a slice copied out of its stack. A copy into VMEM (memory
+    space S(1)) is a prefetch, not such a slice."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    model = build_model(get_config("deepseek-v2-lite"))
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(one_chip, a.shape, a.dtype), tree)
+    B, T = 8, 1024
+    compiled = jax.jit(build_decode_step(model, ServeOptions()),
+                       donate_argnums=(1,)).lower(
+        place(jax.eval_shape(model.init, jax.random.PRNGKey(0))),
+        place(abstract_cache(model, B, T)), _on(one_chip, (B, 1), jnp.int32),
+        _on(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    held_share = re.compile(r"= bf16\[8,(2048,1408|1408,2048)\]\{[^}]*\}")
+    slices = [line.strip() for line in text.splitlines()
+              if (m := held_share.search(line)) and "S(1)" not in m.group()]
+    assert not slices, slices[:3]
