@@ -74,7 +74,8 @@ class VisionConfig:
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                 # dense | moe | ssm | hybrid | encdec | vlm
+    # dense | moe | ssm | hybrid | hybrid_moe | encdec | vlm
+    family: str
     n_layers: int
     d_model: int
     n_heads: int
@@ -111,6 +112,16 @@ class ModelConfig:
     vision: VisionConfig = field(default_factory=VisionConfig)
     # hybrid (zamba2): a shared attention block applied every N ssm blocks
     shared_attn_every: int = 0
+    # hybrid_moe (granite-4.0-h): each layer's mixer, "mamba" or "attention",
+    # each mixer followed by an expert layer
+    layer_types: tuple[str, ...] = ()
+    # muP scalars (1 -> none): embeddings times embedding_multiplier, each
+    # block's output times residual_multiplier before its residual add, the
+    # logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6      # RMSNorm epsilon
     # encoder-decoder (seamless)
     n_encoder_layers: int = 0
     # --- numerics ---

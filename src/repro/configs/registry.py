@@ -3,8 +3,9 @@ from __future__ import annotations
 
 from .base import ModelConfig
 from . import (command_r_plus_104b, deepseek_7b, deepseek_moe_16b,
-               deepseek_v2_lite, gemma2_9b, kimi_k2_1t, llama32_vision_90b,
-               mamba2_370m, seamless_m4t_large_v2, stablelm_12b, zamba2_7b)
+               deepseek_v2_lite, gemma2_9b, granite_4_0_h_small, kimi_k2_1t,
+               llama32_vision_90b, mamba2_370m, seamless_m4t_large_v2,
+               stablelm_12b, zamba2_7b)
 
 _MODULES = {
     "deepseek-7b": deepseek_7b,
@@ -18,6 +19,7 @@ _MODULES = {
     "zamba2-7b": zamba2_7b,
     "mamba2-370m": mamba2_370m,
     "deepseek-v2-lite": deepseek_v2_lite,
+    "granite-4.0-h-small": granite_4_0_h_small,
 }
 
 ARCH_IDS = tuple(_MODULES)

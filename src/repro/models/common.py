@@ -145,7 +145,8 @@ def _zero_centered(cfg) -> bool:
 def apply_norm(p: dict, x, cfg):
     if cfg.use_layernorm:
         return layer_norm(x, p["scale"], p.get("bias"))
-    return rms_norm(x, p["scale"], zero_centered=_zero_centered(cfg))
+    return rms_norm(x, p["scale"], cfg.norm_eps,
+                    zero_centered=_zero_centered(cfg))
 
 
 # ---------------------------------------------------------------------------

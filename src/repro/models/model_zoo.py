@@ -34,17 +34,16 @@ class Model:
         return param_count(self.specs)
 
     def active_param_count(self) -> int:
-        """Per-token active params (MoE discount for roofline MODEL_FLOPS)."""
+        """Per-token active params (MoE discount for roofline MODEL_FLOPS):
+        every expert layer's held routed experts give way to the top-k a
+        token passes through."""
         cfg = self.cfg
-        total = param_count(self.specs)
-        if cfg.family != "moe":
-            return total
         m = cfg.moe
-        routed = m.num_experts * 3 * cfg.d_model * m.d_ff_expert \
-            * (cfg.n_layers - m.first_k_dense)
-        active = m.top_k * 3 * cfg.d_model * m.d_ff_expert \
-            * (cfg.n_layers - m.first_k_dense)
-        return total - routed + active
+        layers = sum(gd.repeat for gd in transformer.layer_plan(cfg)
+                     for b in gd.blocks if b.kind == "moe")
+        per_expert = 3 * cfg.d_model * m.d_ff_expert
+        return (param_count(self.specs)
+                - (m.held - m.top_k) * per_expert * layers)
 
     # -- inputs -------------------------------------------------------------
     def extra_inputs(self, batch: int, seq_len: int, abstract=False):

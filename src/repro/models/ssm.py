@@ -103,7 +103,7 @@ def apply_ssm(p, x, *, cfg, d_model=None, state=None):
                                 chunk=chunk)
     y = y[:, :L].reshape(B, L, d_inner)
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["norm"])
+                 p["norm"], cfg.norm_eps)
     out = jnp.einsum("blk,kd->bld", y, p["out_proj"])
     new_state = None
     if state is not None:
@@ -134,7 +134,7 @@ def apply_ssm_decode(p, x_t, state, *, cfg, d_model=None):
         p["d_skip"])
     y = y.reshape(B, d_inner)
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["norm"])
+                 p["norm"], cfg.norm_eps)
     out = jnp.einsum("bk,kd->bd", y, p["out_proj"])[:, None]      # (B, 1, d)
     new_state = {"conv": window[:, 1:], "ssm": ssm_state}
     return out, new_state

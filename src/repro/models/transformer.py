@@ -4,7 +4,9 @@ Every assigned architecture is expressed as a *layer plan* — a tuple of
 ``GroupDesc`` entries; each group is scanned ``repeat`` times over stacked
 per-layer parameters (compile-time O(1) in depth). Heterogeneous depth
 patterns (gemma2 local/global alternation, DeepSeek first-k-dense, Llama-3.2
-cross-attn interleave, Zamba2 shared block) become multi-block groups.
+cross-attn interleave, Zamba2 shared block, Granite-4.0-H's period of Mamba2
+and attention mixers, each followed by an expert layer) become multi-block
+groups.
 
 Modes: ``train`` (no cache), ``prefill`` (flash attention over the prompt),
 ``decode`` (single-token step over the cache / SSM state). With a cache, the
@@ -65,6 +67,15 @@ def layer_plan(cfg) -> tuple[GroupDesc, ...]:
         if rest:
             groups.append(GroupDesc(rest, (S,)))
         return tuple(groups)
+    if cfg.family == "hybrid_moe":
+        types = cfg.layer_types
+        assert len(types) == cfg.n_layers
+        period = next(p for p in range(1, len(types) + 1)
+                      if types == types[:p] * (len(types) // p))
+        mixer = {"mamba": S, "attention": A}
+        return (GroupDesc(len(types) // period,
+                          tuple(b for t in types[:period]
+                                for b in (mixer[t], BlockDesc("moe")))),)
     if cfg.family == "vlm":
         ce = cfg.vision.cross_every
         assert cfg.n_layers % ce == 0
@@ -239,13 +250,22 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
     def maybe_post(out, p):
         return apply_norm(p["post_norm"], out, cfg) if cfg.post_block_norm else out
 
+    def residual(x, out):
+        """x + out, the block's output scaled by ``residual_multiplier``
+        (in float32, one rounding) where that is not 1."""
+        m = cfg.residual_multiplier
+        if m == 1.0:
+            return x + out
+        return (x.astype(jnp.float32)
+                + out.astype(jnp.float32) * m).astype(x.dtype)
+
     if b.kind in ("attn", "shared_attn"):
         p = shared_params if b.kind == "shared_attn" else bp
         h = apply_norm(p["norm"], x, cfg)
         out, new_cache = apply_attention(
             p["attn"], h, cfg=cfg, window=b.window, positions=positions,
             cache=cache, cache_index=cache_index, causal=b.causal, mode=mode)
-        x = x + maybe_post(out, p)
+        x = residual(x, maybe_post(out, p))
         if b.kind == "shared_attn":  # zamba2 shared block = attn + mlp
             h = apply_norm(p["ffn_norm"], x, cfg)
             x = x + apply_ffn(p["ffn"], h, cfg=cfg)
@@ -254,7 +274,7 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         out, new_cache = apply_mla(bp["attn"], h, cfg=cfg, positions=positions,
                                    cache=cache, cache_index=cache_index,
                                    mode=mode)
-        x = x + maybe_post(out, bp)
+        x = residual(x, maybe_post(out, bp))
     elif b.kind == "parallel":  # command-r: one norm, attn || ffn
         h = apply_norm(bp["norm"], x, cfg)
         out_a, new_cache = apply_attention(
@@ -264,19 +284,19 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
         x = x + out_a + out_f
     elif b.kind == "ffn":
         h = apply_norm(bp["norm"], x, cfg)
-        x = x + maybe_post(apply_ffn(bp["ffn"], h, cfg=cfg), bp)
+        x = residual(x, maybe_post(apply_ffn(bp["ffn"], h, cfg=cfg), bp))
     elif b.kind == "moe":
         h = apply_norm(bp["norm"], x, cfg)
         out, aux, rows = apply_moe(bp["moe"], h, cfg=cfg, dist=dist,
                                    layer=layer)
-        x = x + maybe_post(out, bp)
+        x = residual(x, maybe_post(out, bp))
     elif b.kind == "ssm":
         h = apply_norm(bp["norm"], x, cfg)
         if mode == "decode":
             out, new_cache = apply_ssm_decode(bp["ssm"], h, cache, cfg=cfg)
         else:
             out, new_cache = apply_ssm(bp["ssm"], h, cfg=cfg, state=cache)
-        x = x + maybe_post(out, bp)
+        x = residual(x, maybe_post(out, bp))
     elif b.kind == "cross_attn":
         h = apply_norm(bp["norm"], x, cfg)
         if mode == "decode":
@@ -289,7 +309,7 @@ def _apply_block(bp, x, b: BlockDesc, *, cfg, dist, mode, cache, cache_index,
                              "cv": v.astype(cache["cv"].dtype)}
         out, _ = apply_attention(bp["attn"], h, cfg=cfg, cross_kv=kv,
                                  positions=positions, mode=mode)
-        x = x + maybe_post(out, bp)
+        x = residual(x, maybe_post(out, bp))
     else:
         raise ValueError(b.kind)
     return x, new_cache, aux, rows
@@ -396,6 +416,8 @@ def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
         jnp.dtype(cfg.activ_dtype))
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
 
     if cache_index is None:
         positions = jnp.arange(Sq)[None, :]
@@ -446,6 +468,8 @@ def forward(params, inputs, *, cfg, dist: DistContext = LOCAL, mode="train",
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     logits = softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     new_cache = None
     if cache is not None:
         new_cache = {"groups": new_groups}

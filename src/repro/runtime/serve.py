@@ -36,6 +36,21 @@ def abstract_cache(model: Model, batch: int, max_len: int, enc_len: int = 0):
         lambda: model.init_cache(batch, max_len, enc_len=enc_len))
 
 
+def cache_split_bytes(cache) -> tuple[int, int]:
+    """(attention bytes, SSM state bytes) of a cache's layer leaves: the
+    rows of every attention kind (K/V, latent, cross) and the conv and scan
+    state of the SSM blocks; the expert counters are neither."""
+    kv = state = 0
+    for group in cache["groups"].values():
+        for block in group.values():
+            n = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(block))
+            if "ssm" in block:
+                state += n
+            else:
+                kv += n
+    return kv, state
+
+
 def build_prefill_step(model: Model, opts: ServeOptions, mesh=None):
     dist = make_dist(mesh)
 
@@ -132,14 +147,18 @@ class ServeSession:
 
         Under a profiler session each call records the spans ``serve.*``
         of one request (``tracing``): the spans time the host's dispatch,
-        the device runs behind them. A model with expert layers adds
-        ``experts_held`` to ``serve.generate`` and, read once at the end of
-        the call, the cache's counters ``moe_rows_routed`` (assignments that
-        fell on the held experts), ``moe_rows_computed`` (rows the grouped
-        kernel computed) and ``moe_experts_read`` (held experts with a row,
-        whose weights the kernel read, once a kernel call), summed over
-        layers, prefill and decode, and the decode steps' part of the first
-        and last, ``moe_decode_rows_routed`` and ``moe_decode_experts_read``."""
+        the device runs behind them. ``serve.generate`` gives the bytes of
+        the cache the call allocated, ``cache_bytes``, and their split into
+        attention rows, ``kv_cache_bytes``, and SSM conv and scan state,
+        ``ssm_state_bytes`` (``cache_split_bytes``). A model with expert
+        layers adds ``experts_held`` to ``serve.generate`` and, read once
+        at the end of the call, the cache's counters ``moe_rows_routed``
+        (assignments that fell on the held experts), ``moe_rows_computed``
+        (rows the grouped kernel computed) and ``moe_experts_read`` (held
+        experts with a row, whose weights the kernel read, once a kernel
+        call), summed over layers, prefill and decode, and the decode
+        steps' part of the first and last, ``moe_decode_rows_routed`` and
+        ``moe_decode_experts_read``."""
         B, S = prompts.shape
         moe = self.model.cfg.moe
         held = {"experts_held": moe.held} if moe.num_experts else {}
@@ -150,8 +169,9 @@ class ServeSession:
                 cache = self.model.init_cache(B, S + max_new_tokens,
                                               enc_len=enc_len)
             if call is not None:
-                call.set(cache_bytes=sum(
-                    x.nbytes for x in jax.tree.leaves(cache["groups"])))
+                kv, state = cache_split_bytes(cache)
+                call.set(cache_bytes=kv + state, kv_cache_bytes=kv,
+                         ssm_state_bytes=state)
             inputs = {"tokens": prompts, **(extras or {})}
             with tracing.span("serve.prefill"):
                 last_logits, cache = self.prefill(self.params, inputs, cache)

@@ -2,11 +2,11 @@
 
 The TPU compiler refuses what the Pallas interpreter accepts: blocks off the
 (8, 128) tiling, too much VMEM, a program larger than HBM. These tests compile
-the kernels at the published widths of the models that use them, and the
-full-width deepseek-7b and deepseek-v2-lite decode steps, for one chip of a
-``v5e:2x2`` topology; each decode step's compiled program must also write
-its donated cache in place, and deepseek-v2-lite's must read its experts'
-weights in place.
+the kernels at the published widths of the models that use them, the
+full-width deepseek-7b and deepseek-v2-lite decode steps and granite's
+longest prefill, for one chip of a ``v5e:2x2`` topology; each decode step's
+compiled program must also write its donated cache in place, and
+deepseek-v2-lite's must read its experts' weights in place.
 Nothing runs; only the compiler is exercised.
 
 The topology is described inside a fixture (never at import): only one
@@ -27,9 +27,11 @@ from repro.kernels.moe_gmm import expert_ffn_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.models.model_zoo import build_model
 from repro.models.moe import dispatch_rows
-from repro.runtime.serve import ServeOptions, abstract_cache, build_decode_step
+from repro.runtime.serve import (ServeOptions, abstract_cache,
+                                 build_decode_step, build_prefill_step)
 
 V5E_HBM_BYTES = 16 * 2**30
+V5E_BYTES_LIMIT = 16_909_336_064  # what the chip's memory_stats allows
 
 
 @pytest.fixture(scope="module")
@@ -244,3 +246,33 @@ def test_deepseek_v2_lite_decode_reads_the_held_experts_in_place(
     slices = [line.strip() for line in text.splitlines()
               if (m := held_share.search(line)) and "S(1)" not in m.group()]
     assert not slices, slices[:3]
+
+
+def test_granite_prefill_at_g4hs_docdigests_longest_prompt_fits_one_v5e(
+        one_chip, monkeypatch):
+    """granite-4.0-h-small at published widths (20 layers: 18 Mamba2, 2
+    GQA, each followed by its expert layer, 8 of 72 experts held), one
+    prompt of 24,576 tokens, the cache of K/V rows and SSM state donated,
+    with the Pallas kernels the chip runs (flash, SSD scan, grouped
+    experts): weights, cache, outputs and temporaries within what the
+    chip allows (about 15.2e9 of 16.9e9 bytes). The prefill's
+    temporaries, about 260 KB a token, mostly the Mamba2 layers', are what
+    keep a 32k prompt out."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    model = build_model(get_config("granite-4.0-h-small"))
+    place = lambda tree: jax.tree.map(
+        lambda a: _on(one_chip, a.shape, a.dtype), tree)
+    S = 24576
+    compiled = jax.jit(build_prefill_step(model, ServeOptions()),
+                       donate_argnums=(2,)).lower(
+        place(model.abstract()), {"tokens": _on(one_chip, (1, S), jnp.int32)},
+        place(abstract_cache(model, 1, S + 128))).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < V5E_BYTES_LIMIT, total
+    assert m.argument_size_in_bytes > 8_459_000_000     # the weights
+    text = compiled.as_text()
+    for kernel in ("ssd_scan_pallas", "flash_attention_pallas",
+                   "expert_ffn_pallas"):
+        assert kernel in text, kernel

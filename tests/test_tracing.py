@@ -61,9 +61,10 @@ def test_generate_records_one_request(session, prompts, tmp_path):
     assert [r.attrs["step"] for r in recs[4:-1]] == list(range(1, NEW))
     B, S = prompts.shape
     cache = session.model.init_cache(B, S + NEW)
+    kv = sum(x.nbytes for x in jax.tree.leaves(cache))
     assert top.attrs == {
-        "batch": B, "prompt_len": S, "new_tokens": NEW,
-        "cache_bytes": sum(x.nbytes for x in jax.tree.leaves(cache))}
+        "batch": B, "prompt_len": S, "new_tokens": NEW, "cache_bytes": kv,
+        "kv_cache_bytes": kv, "ssm_state_bytes": 0}
     for r in recs:
         assert top.start_ns <= r.start_ns <= r.end_ns <= top.end_ns
     for a, b in zip(recs[1:], recs[2:]):
